@@ -32,7 +32,7 @@ def _kernel(x_ref, o_ref, *, n: int):
 
 
 def tree_reduce(shards: jnp.ndarray, *, block: int = 4096,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """shards: (N, L) → (L,) sum with fp32 tree accumulation."""
     n, L = shards.shape
     block = min(block, L)

@@ -16,60 +16,62 @@ jnp oracle is ``models.ssm.ssd_chunked`` / ``ssd_reference``).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
-            chunk: int, n_chunks: int):
-    ci = pl.program_id(1)
-
-    @pl.when(ci == 0)
-    def _init():
-        state_scr[...] = jnp.zeros_like(state_scr)
-
+def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr):
     x = x_ref[0].astype(jnp.float32)          # (Q, hd)
-    dt = dt_ref[0].astype(jnp.float32)        # (Q,)
-    A = a_ref[0]                              # scalar (per head)
+    dt = dt_ref[0].astype(jnp.float32)        # (Q, 1)
+    A = a_ref[pl.program_id(0)]               # scalar (per head), SMEM
     Bm = b_ref[0].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)         # (Q, N)
 
-    dA = dt * A                               # (Q,) ≤ 0
-    cs = jnp.cumsum(dA)
-    seg = cs[:, None] - cs[None, :]
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        state_scr[...] = jnp.zeros_like(state_scr)
+
+    # Per-step scalars arrive as a (Q, 1) column (sublane-major, as the
+    # chip tiles a block); the row copies and the cumulative sums are
+    # masked reductions over (Q, Q) iotas, so no 1-D vector is relaid.
     Q = dt.shape[0]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(tri, jnp.exp(seg), 0.0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    dA = dt * A                               # (Q, 1) ≤ 0
+    dt_row = jnp.sum(jnp.where(row == col, dt, 0.0), axis=0, keepdims=True)
+    dA_row = dt_row * A                       # (1, Q)
+    cs = jnp.sum(jnp.where(col <= row, dA_row, 0.0), axis=1,
+                 keepdims=True)               # (Q, 1) inclusive cumsum
+    cs_row = jnp.sum(jnp.where(row <= col, dA, 0.0), axis=0,
+                     keepdims=True)           # (1, Q)
+    total = jnp.sum(dA_row, axis=1, keepdims=True)   # (1, 1)
+    L = jnp.where(row >= col, jnp.exp(cs - cs_row), 0.0)
 
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    M = scores * L * dt[None, :]
+    M = scores * L * dt_row
     y_intra = jax.lax.dot(M, x, preferred_element_type=jnp.float32)
 
     state_in = state_scr[...]                 # (hd, N)
-    in_decay = jnp.exp(cs)                    # decay from chunk start
-    y_inter = jax.lax.dot(Cm, state_in.T,
-                          preferred_element_type=jnp.float32) * \
-        in_decay[:, None]
-    # wrong orientation guard: y_inter rows index Q, cols hd
+    y_inter = jax.lax.dot_general(Cm, state_in, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32) * \
+        jnp.exp(cs)                           # decay from chunk start
     y_ref[0, :, :] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    decay_to_end = jnp.exp(cs[-1] - cs)       # (Q,)
+    w = dt * jnp.exp(total - cs)              # (Q, 1) dt·decay to chunk end
     contrib = jax.lax.dot_general(
-        x * (dt * decay_to_end)[:, None], Bm, (((0,), (0,)), ((), ())),
+        x * w, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)   # (hd, N)
-    state_scr[...] = jnp.exp(cs[-1]) * state_in + contrib
+    state_scr[...] = jnp.exp(total) * state_in + contrib
 
 
 def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: int = 64,
-             interpret: bool = True):
+             interpret: bool = False):
     """x: (B,S,H,hd); dt: (B,S,H); A: (H,); B/C: (B,S,G,N) with H%G==0.
-    Returns y: (B,S,H,hd)."""
+    Returns y: (B,S,H,hd).  On the chip ``chunk`` is a multiple of 8 or
+    covers all of S (the block rule for the sequence axis)."""
     Bsz, S, H, hd = x.shape
     G, N = Bmat.shape[2], Bmat.shape[3]
     rep = H // G
@@ -78,7 +80,7 @@ def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: int = 64,
     pad = nc * chunk - S
 
     xb = jnp.moveaxis(x, 2, 1).reshape(Bsz * H, S, hd)
-    dtb = jnp.moveaxis(dt, 2, 1).reshape(Bsz * H, S)
+    dtb = jnp.moveaxis(dt, 2, 1).reshape(Bsz * H, S, 1)
     Bb = jnp.repeat(Bmat, rep, axis=2)
     Cb = jnp.repeat(Cmat, rep, axis=2)
     Bb = jnp.moveaxis(Bb, 2, 1).reshape(Bsz * H, S, N)
@@ -87,17 +89,17 @@ def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk: int = 64,
 
     if pad:
         xb = jnp.pad(xb, ((0, 0), (0, pad), (0, 0)))
-        dtb = jnp.pad(dtb, ((0, 0), (0, pad)))
+        dtb = jnp.pad(dtb, ((0, 0), (0, pad), (0, 0)))
         Bb = jnp.pad(Bb, ((0, 0), (0, pad), (0, 0)))
         Cb = jnp.pad(Cb, ((0, 0), (0, pad), (0, 0)))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, n_chunks=nc),
+        _kernel,
         grid=(Bsz * H, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, c: (bh, c)),
-            pl.BlockSpec((1,), lambda bh, c: (bh,)),
+            pl.BlockSpec((1, chunk, 1), lambda bh, c: (bh, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, N), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, c: (bh, c, 0)),
         ],

@@ -23,15 +23,6 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 
-def _axis_kwargs(n_axes: int):
-    """``axis_types`` exists from jax 0.5; omit it on older runtimes where
-    every axis is Auto anyway."""
-    import jax
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False, devices=None):
     """(16, 16) ``(data, model)`` single-pod or (2, 16, 16)
     ``(pod, data, model)`` multi-pod mesh."""
@@ -41,7 +32,8 @@ def make_production_mesh(*, multi_pod: bool = False, devices=None):
     if devices is not None:
         devs = np.asarray(devices).reshape(shape)
         return jax.sharding.Mesh(devs, axes)
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
@@ -51,7 +43,7 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
         devs = np.asarray(devices).reshape(tuple(shape))
         return jax.sharding.Mesh(devs, tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def fred_device_order(n_devices: int, mp: int, dp: int, pp: int) -> np.ndarray:

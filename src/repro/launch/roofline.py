@@ -172,8 +172,6 @@ def collective_bytes_from_hlo(hlo: str) -> dict:
 
 def collect_cost(compiled) -> dict:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older API returned [dict]
-        ca = ca[0]
     keep = {}
     for k in ("flops", "bytes accessed", "transcendentals", "optimal_seconds"):
         if k in ca:

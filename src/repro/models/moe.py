@@ -173,7 +173,6 @@ def moe_ffn_ep(params, x, cfg, *, mesh, ep_axis: str,
     ``x`` must shard its batch dim over ``ep_axis`` (n | B) and expert
     weights their leading E dim (E % n == 0).
     """
-    from repro.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -206,11 +205,11 @@ def moe_ffn_ep(params, x, cfg, *, mesh, ep_axis: str,
                              combine_w, T, k)
         return out.reshape(x_l.shape), jax.lax.pmean(aux, ep_axis)
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(), P(ep_axis), P(ep_axis), P(ep_axis),
-                             P(ep_axis)),
-                   out_specs=(P(ep_axis), P()),
-                   check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(), P(ep_axis), P(ep_axis), P(ep_axis),
+                                 P(ep_axis)),
+                       out_specs=(P(ep_axis), P()),
+                       check_vma=False)
     out, aux = fn(_v(params["router"]), _v(params["w_gate"]),
                   _v(params["w_up"]), _v(params["w_down"]), x)
 

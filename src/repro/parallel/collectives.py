@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 from .compress import ef_quantize, dequantize
 
 
@@ -139,7 +138,7 @@ def build_sync(mesh: Mesh, mode: str = "hierarchical",
             # all_gather(tiled) makes values equal across the inner axis
             # but the vma type system still marks them varying — the
             # replication is semantic, so disable the static check here
-            return shard_map(body, mesh=mesh,
+            return jax.shard_map(body, mesh=mesh,
                                  in_specs=(in_spec, P(axes)),
                                  out_specs=(out_spec, P(axes)),
                                  check_vma=False)(grads, errors)
@@ -149,7 +148,7 @@ def build_sync(mesh: Mesh, mode: str = "hierarchical",
         def body(gs):
             gs = jax.tree.map(lambda a: a[0], gs)
             return jax.tree.map(sync_leaf, gs)
-        return shard_map(body, mesh=mesh, in_specs=in_spec,
+        return jax.shard_map(body, mesh=mesh, in_specs=in_spec,
                              out_specs=out_spec, check_vma=False)(grads)
     return sync
 

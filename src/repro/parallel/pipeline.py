@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import pcast_varying, shard_map
-
 
 def pipeline_fn(stage_fn: Callable, n_stages: int, n_microbatches: int,
                 mesh: Mesh, pipe_axis: str = "pipe"):
@@ -47,9 +45,10 @@ def pipeline_fn(stage_fn: Callable, n_stages: int, n_microbatches: int,
         perm = [(i, (i + 1) % S) for i in range(S)]
 
         # initial carries are logically per-stage (varying over pipe)
-        buf = pcast_varying(jnp.zeros_like(x_mb[0]), pipe_axis)
-        outs0 = pcast_varying(jnp.zeros((M,) + x_mb.shape[1:], x_mb.dtype),
-                              pipe_axis)
+        buf = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (pipe_axis,),
+                            to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros((M,) + x_mb.shape[1:], x_mb.dtype),
+                              (pipe_axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -71,7 +70,7 @@ def pipeline_fn(stage_fn: Callable, n_stages: int, n_microbatches: int,
         (_, outs), _ = jax.lax.scan(tick, (buf, outs0), jnp.arange(T))
         return outs[None]                     # (1, M, ...) per stage
 
-    mapped = shard_map(sharded, mesh=mesh,
+    mapped = jax.shard_map(sharded, mesh=mesh,
                            in_specs=(P(pipe_axis), P()),
                            out_specs=P(pipe_axis))
 

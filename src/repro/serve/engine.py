@@ -62,6 +62,11 @@ class Engine:
         # per-decode-step wall times of the most recent run_batch (first
         # entry includes the decode jit compile; dryrun --serving drops it)
         self.decode_step_s: List[float] = []
+        # prefill wall time of the most recent run_batch (includes its
+        # compile on the first call) and the (B, V) logits that chose that
+        # batch's last tokens
+        self.prefill_s: float = 0.0
+        self.last_logits: Optional[jnp.ndarray] = None
 
     def _sample(self, logits: jnp.ndarray, reqs: List[Request],
                 key) -> np.ndarray:
@@ -95,7 +100,10 @@ class Engine:
         toks = np.zeros((B, plen), np.int32)
         for i, r in enumerate(requests):
             toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
+        ts = time.perf_counter()
         logits, state = self._prefill(self.params, {"tokens": jnp.asarray(toks)})
+        logits.block_until_ready()
+        self.prefill_s = time.perf_counter() - ts
 
         outs: List[List[int]] = [[] for _ in requests]
         done = np.zeros(B, bool)
@@ -120,6 +128,7 @@ class Engine:
             next_tok = self._sample(logits, requests, key)
 
         dt = time.perf_counter() - t0
+        self.last_logits = logits
         for r, o in zip(requests, outs):
             r.output = o
             r.latency_s = dt

@@ -8,8 +8,7 @@ implements the fault-tolerance contract:
     data-pipeline state, step counter);
   * SIGTERM/SIGINT → synchronous final checkpoint before exit (preemption
     safety);
-  * per-step wall-time and token-throughput accounting with an MFU
-    estimate against the configured peak;
+  * per-step wall-time and token-throughput accounting;
   * straggler hook: a callback observing per-step durations; the default
     policy logs p50/p95 and flags steps > ``straggler_factor``×p50 (on a
     real multi-host deployment this feeds the controller that re-shards
@@ -45,7 +44,6 @@ class TrainerConfig:
     keep_checkpoints: int = 3
     seed: int = 0
     straggler_factor: float = 2.0
-    peak_flops_per_device: float = 197e12
 
 
 class Trainer:

@@ -32,7 +32,8 @@ def test_flash_attention_sweep(B, S, H, hd, dtype, causal):
          ).astype(dtype)
     v = jax.random.normal(jax.random.fold_in(KEY, 2),
                           (B, S, H, hd)).astype(dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     ref = dense_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -49,7 +50,7 @@ def test_ssd_scan_sweep(S, chunk, G):
     A = -jnp.exp(jax.random.normal(jax.random.fold_in(KEY, 2), (H,)) * 0.3)
     Bm = jax.random.normal(jax.random.fold_in(KEY, 3), (B, S, G, N)) * 0.4
     Cm = jax.random.normal(jax.random.fold_in(KEY, 4), (B, S, G, N)) * 0.4
-    y = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+    y = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     yr = ssd_reference(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                atol=5e-4, rtol=5e-3)
@@ -60,7 +61,7 @@ def test_ssd_scan_sweep(S, chunk, G):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_tree_reduce_sweep(n, L, block, dtype):
     shards = (jax.random.normal(KEY, (n, L)) * 2).astype(dtype)
-    out = tree_reduce(shards, block=block)
+    out = tree_reduce(shards, block=block, interpret=True)
     ref = ref_reduce(shards)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
@@ -69,11 +70,11 @@ def test_tree_reduce_sweep(n, L, block, dtype):
 @pytest.mark.parametrize("n,block", [(100, 64), (5000, 512), (4096, 1024)])
 def test_quant8_matches_jnp(n, block):
     x = jax.random.normal(KEY, (n,)) * 5.0
-    q1, s1 = p_q(x, block)
+    q1, s1 = p_q(x, block, interpret=True)
     q2, s2 = j_q(x, block)
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q2))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
-    x1 = p_dq(q1, s1, block)
+    x1 = p_dq(q1, s1, block, interpret=True)
     x2 = j_dq(q2, s2, block)
     np.testing.assert_allclose(np.asarray(x1), np.asarray(x2), rtol=1e-6)
     # quantization error bounded by half a quantum per block

@@ -154,6 +154,25 @@ def test_moe_ep_ffn_fn_requires_ep_axis():
     assert float(jax.numpy.max(jax.numpy.abs(got - ref))) < 1e-5
 
 
+def test_chip_smoke_mesh_phase_on_4_devices():
+    """``chip_smoke.py --chips 4``'s phase at a tiny size: the Trainer on
+    a (2, 2) FSDP+TP mesh and on a (1, 1) mesh agree step by step."""
+    out = run_with_devices(f"""
+        import importlib.util
+        from repro.configs.registry import get_config
+        from repro.models.config import ShapeConfig
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", {str(Path(SRC).parent / "chip_smoke.py")!r})
+        smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(smoke)
+        cfg = smoke.one_period(get_config("zamba2-2.7b").reduced())
+        obs = smoke.mesh_phase(cfg, ShapeConfig("t", "train", 64, 2), steps=2)
+        assert len(obs["losses_2x2"]) == len(obs["losses_1x1"]) == 2
+        print("MESH_PHASE_OK", obs["max_abs_loss_diff"])
+    """, n=4)
+    assert "MESH_PHASE_OK" in out
+
+
 @pytest.mark.slow
 def test_error_feedback_reduces_bias_over_steps():
     run_with_devices("""

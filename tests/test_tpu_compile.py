@@ -1,0 +1,94 @@
+"""Ahead-of-time compiles of the Pallas kernels for a described TPU v5e.
+
+The TPU compiler is installed with jaxlib and compiles for a chip that is
+described, not attached, so these tests need no accelerator: they catch
+block shapes, layouts and VMEM use that interpret mode accepts and the
+chip's compiler refuses.  Widths are the real ones of the main path
+(zamba2 / mamba2 heads, gradient-compression blocks).  Nothing runs and
+no time is measured.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and pytest-xdist workers all
+import this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.quant8 import dequantize, quantize
+from repro.kernels.reduce_tree import tree_reduce
+from repro.kernels.ssd_scan import ssd_scan
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("hd", [80, 128])
+def test_flash_attention_compiles(one_chip, hd):
+    B, S, H = 1, 2048, 32
+    qkv = ((B, S, H, hd), jnp.bfloat16)
+    text = _compile_text(lambda q, k, v: flash_attention(q, k, v),
+                         one_chip, qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+def test_ssd_scan_compiles(one_chip):
+    # mamba2-1.3b: 64 heads of 64, one B/C group of state 128
+    B, S, H, hd, G, N = 1, 2048, 64, 64, 1, 128
+    f32 = jnp.float32
+    text = _compile_text(
+        lambda x, dt, A, Bm, Cm: ssd_scan(x, dt, A, Bm, Cm), one_chip,
+        ((B, S, H, hd), f32), ((B, S, H), f32), ((H,), f32),
+        ((B, S, G, N), f32), ((B, S, G, N), f32))
+    assert "tpu_custom_call" in text
+
+
+def test_quantize_compiles(one_chip):
+    text = _compile_text(lambda x: quantize(x, 1024), one_chip,
+                         ((1 << 20,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_dequantize_compiles(one_chip):
+    n, block = 1 << 20, 1024
+    text = _compile_text(lambda q, s: dequantize(q, s, block), one_chip,
+                         ((n,), jnp.int8), ((n // block,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_tree_reduce_compiles(one_chip):
+    text = _compile_text(lambda x: tree_reduce(x), one_chip,
+                         ((16, 1 << 20), jnp.bfloat16))
+    assert "tpu_custom_call" in text
