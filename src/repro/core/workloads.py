@@ -125,9 +125,9 @@ def paper_workloads() -> List[Workload]:
 # per-NPU memory-feasibility model (ISSUE 3: richer sweep objectives)
 # --------------------------------------------------------------------------
 
-# Production-chip assumption used across the JAX substrate (launch/perf.py
-# hillclimb notes, the arctic-480b optimizer-mode comment in
-# parallel/policy.py): 16 GiB of HBM per NPU/chip.
+# Production-chip assumption used across the JAX substrate (the
+# arctic-480b optimizer-mode comment in parallel/policy.py): 16 GiB of HBM
+# per NPU/chip.
 DEFAULT_NPU_HBM_BYTES = 16 * 2**30
 
 # Activation multiplier vs the layer-boundary tensor, per remat setting.

@@ -94,54 +94,55 @@ def apply_attention(p, cfg, pcfg, x, *, positions, mode: str = "train",
                     constrain=lambda t, kind="residual": t,
                     ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     """Unified attention. Returns (out, new_cache)."""
-    B, S, d = x.shape
-    cross = kv_x is not None
-    src = kv_x if cross else x
-    new_cache = cache
+    with jax.named_scope("attention"):
+        B, S, d = x.shape
+        cross = kv_x is not None
+        src = kv_x if cross else x
+        new_cache = cache
 
-    if mode == "decode" and cross:
-        # cross-attention at decode reads the static (precomputed) cache
-        q = x @ p["wq"]
-        if "bq" in p:
-            q = q + p["bq"]
-        q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
-        if "q_norm" in p:
-            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        out = decode_attention(q, cache.k, cache.v,
-                               jnp.full((B,), cache.k.shape[1], jnp.int32))
-        return out.reshape(B, S, -1) @ p["wo"], cache
+        if mode == "decode" and cross:
+            # cross-attention at decode reads the static (precomputed) cache
+            q = x @ p["wq"]
+            if "bq" in p:
+                q = q + p["bq"]
+            q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+            if "q_norm" in p:
+                q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            out = decode_attention(q, cache.k, cache.v,
+                                   jnp.full((B,), cache.k.shape[1], jnp.int32))
+            return out.reshape(B, S, -1) @ p["wo"], cache
 
-    q, k, v = _project_qkv(p, cfg, x, src, positions, use_rope=not cross)
-    q = constrain(q, "q_heads")
-    k = constrain(k, "kv_heads")
-    v = constrain(v, "kv_heads")
+        q, k, v = _project_qkv(p, cfg, x, src, positions, use_rope=not cross)
+        q = constrain(q, "q_heads")
+        k = constrain(k, "kv_heads")
+        v = constrain(v, "kv_heads")
 
-    if mode == "decode":
-        # write new K/V at cache_index (rolling slot for SWA buffers)
-        S_cache = cache.k.shape[1]
-        write_pos = cache_index % S_cache if window else cache_index
-        kc = _write_cache(cache.k, k, write_pos)
-        vc = _write_cache(cache.v, v, write_pos)
-        valid = jnp.minimum(cache_index + 1, S_cache)
-        out = decode_attention(q, kc, vc, jnp.broadcast_to(valid, (B,)))
-        new_cache = KVCache(kc, vc)
-    else:
-        if cross:
-            out = chunked_attention(q, k, v, causal=False,
-                                    q_chunk=pcfg.attn_q_chunk,
-                                    k_chunk=pcfg.attn_k_chunk)
-        elif S <= 512:
-            out = dense_attention(q, k, v, causal=causal, window=window)
+        if mode == "decode":
+            # write new K/V at cache_index (rolling slot for SWA buffers)
+            S_cache = cache.k.shape[1]
+            write_pos = cache_index % S_cache if window else cache_index
+            kc = _write_cache(cache.k, k, write_pos)
+            vc = _write_cache(cache.v, v, write_pos)
+            valid = jnp.minimum(cache_index + 1, S_cache)
+            out = decode_attention(q, kc, vc, jnp.broadcast_to(valid, (B,)))
+            new_cache = KVCache(kc, vc)
         else:
-            out = chunked_attention(q, k, v, causal=causal, window=window,
-                                    q_chunk=pcfg.attn_q_chunk,
-                                    k_chunk=pcfg.attn_k_chunk)
-        if mode == "prefill":
-            new_cache = _build_cache(k, v,
-                                     cache_len=cache_len or k.shape[1],
-                                     window=window)
-    B2, S2 = out.shape[:2]
-    return out.reshape(B2, S2, -1) @ p["wo"], new_cache
+            if cross:
+                out = chunked_attention(q, k, v, causal=False,
+                                        q_chunk=pcfg.attn_q_chunk,
+                                        k_chunk=pcfg.attn_k_chunk)
+            elif S <= 512:
+                out = dense_attention(q, k, v, causal=causal, window=window)
+            else:
+                out = chunked_attention(q, k, v, causal=causal, window=window,
+                                        q_chunk=pcfg.attn_q_chunk,
+                                        k_chunk=pcfg.attn_k_chunk)
+            if mode == "prefill":
+                new_cache = _build_cache(k, v,
+                                         cache_len=cache_len or k.shape[1],
+                                         window=window)
+        B2, S2 = out.shape[:2]
+        return out.reshape(B2, S2, -1) @ p["wo"], new_cache
 
 
 def _write_cache(buf, kv, pos):
@@ -191,7 +192,8 @@ def init_mlp(key, cfg, dtype=jnp.float32):
 
 
 def apply_mlp(p, x):
-    return swiglu(x @ p["w_gate"], x @ p["w_up"]) @ p["w_down"]
+    with jax.named_scope("mlp"):
+        return swiglu(x @ p["w_gate"], x @ p["w_up"]) @ p["w_down"]
 
 
 # --------------------------------------------------------------------------

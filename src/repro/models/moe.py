@@ -120,36 +120,39 @@ def moe_ffn(params, x, cfg, *, capacity_factor: float | None = None,
     data, E over model when experts are TP-sharded) so the dispatch→expert
     boundary reshards with one all-to-all-class transfer instead of
     gathering every token onto every expert shard."""
-    B, S, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    cf = capacity_factor or cfg.capacity_factor
-    G = n_groups or B                      # per-sequence groups by default
-    T_g = B * S // G
-    xg = x.reshape(G, T_g, d)
-    capacity = max(int(math.ceil(T_g * k * cf / E)), 4)
-    capacity = -(-capacity // 4) * 4
+    with jax.named_scope("moe"):
+        B, S, d = x.shape
+        E, k = cfg.n_experts, cfg.top_k
+        cf = capacity_factor or cfg.capacity_factor
+        G = n_groups or B                      # per-sequence groups by default
+        T_g = B * S // G
+        xg = x.reshape(G, T_g, d)
+        capacity = max(int(math.ceil(T_g * k * cf / E)), 4)
+        capacity = -(-capacity // 4) * 4
 
-    buckets, flat_slot, combine_w, aux = jax.vmap(
-        lambda t: _group_dispatch(t, params["router"], E, k, capacity))(xg)
-    # buckets: (G, E, C, d) — G carries the data sharding end to end
-    buckets = constrain(buckets, "moe_buckets")
+        buckets, flat_slot, combine_w, aux = jax.vmap(
+            lambda t: _group_dispatch(t, params["router"], E, k, capacity))(xg)
+        # buckets: (G, E, C, d) — G carries the data sharding end to end
+        buckets = constrain(buckets, "moe_buckets")
 
-    g = jnp.einsum("gecd,edf->gecf", buckets, _v(params["w_gate"]))
-    u = jnp.einsum("gecd,edf->gecf", buckets, _v(params["w_up"]))
-    h = swiglu(g, u)
-    y = jnp.einsum("gecf,efd->gecd", h, _v(params["w_down"]))
-    y = constrain(y, "moe_buckets")
+        g = jnp.einsum("gecd,edf->gecf", buckets, _v(params["w_gate"]))
+        u = jnp.einsum("gecd,edf->gecf", buckets, _v(params["w_up"]))
+        h = swiglu(g, u)
+        y = jnp.einsum("gecf,efd->gecd", h, _v(params["w_down"]))
+        y = constrain(y, "moe_buckets")
 
-    out = jax.vmap(lambda ye, fs, cw: _group_combine(
-        ye.reshape(E * capacity, d), fs, cw, T_g, k))(y, flat_slot, combine_w)
-    out = out.reshape(B, S, d)
+        out = jax.vmap(lambda ye, fs, cw: _group_combine(
+            ye.reshape(E * capacity, d), fs, cw, T_g, k))(y, flat_slot,
+                                                           combine_w)
+        out = out.reshape(B, S, d)
 
-    if cfg.moe_dense_ff:
-        dn = params["dense"]
-        x2d = x.reshape(-1, d)
-        dense = swiglu(x2d @ _v(dn["w_gate"]), x2d @ _v(dn["w_up"])) @ _v(dn["w_down"])
-        out = out + dense.reshape(B, S, d)
-    return out, jnp.mean(aux)
+        if cfg.moe_dense_ff:
+            dn = params["dense"]
+            x2d = x.reshape(-1, d)
+            dense = swiglu(x2d @ _v(dn["w_gate"]),
+                           x2d @ _v(dn["w_up"])) @ _v(dn["w_down"])
+            out = out + dense.reshape(B, S, d)
+        return out, jnp.mean(aux)
 
 
 def moe_ffn_ep(params, x, cfg, *, mesh, ep_axis: str,
@@ -173,53 +176,55 @@ def moe_ffn_ep(params, x, cfg, *, mesh, ep_axis: str,
     ``x`` must shard its batch dim over ``ep_axis`` (n | B) and expert
     weights their leading E dim (E % n == 0).
     """
-    from jax.sharding import PartitionSpec as P
+    with jax.named_scope("moe"):
+        from jax.sharding import PartitionSpec as P
 
-    B, S, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    cf = capacity_factor or cfg.capacity_factor
-    n = mesh.shape[ep_axis]
-    if B % n or E % n:
-        raise ValueError(f"moe_ffn_ep: batch {B} and n_experts {E} must "
-                         f"both divide over ep_axis {ep_axis!r} (size {n})")
-    T_l = B * S // n                       # tokens per EP rank
-    capacity = max(int(math.ceil(T_l * k * cf / E)), 4)
-    capacity = -(-capacity // 4) * 4
+        B, S, d = x.shape
+        E, k = cfg.n_experts, cfg.top_k
+        cf = capacity_factor or cfg.capacity_factor
+        n = mesh.shape[ep_axis]
+        if B % n or E % n:
+            raise ValueError(f"moe_ffn_ep: batch {B} and n_experts {E} must "
+                             f"both divide over ep_axis {ep_axis!r} "
+                             f"(size {n})")
+        T_l = B * S // n                       # tokens per EP rank
+        capacity = max(int(math.ceil(T_l * k * cf / E)), 4)
+        capacity = -(-capacity // 4) * 4
 
-    def shard_fn(router_w, wg, wu, wd, x_l):
-        T = x_l.shape[0] * x_l.shape[1]
-        x2d = x_l.reshape(T, d)
-        buckets, flat_slot, combine_w, aux = _group_dispatch(
-            x2d, router_w, E, k, capacity)
-        # dispatch A2A: keep E/n experts, gather every rank's C slots
-        b = jax.lax.all_to_all(buckets, ep_axis, split_axis=0,
-                               concat_axis=1, tiled=True)
-        g = jnp.einsum("ecd,edf->ecf", b, wg)
-        u = jnp.einsum("ecd,edf->ecf", b, wu)
-        h = swiglu(g, u)
-        y = jnp.einsum("ecf,efd->ecd", h, wd)
-        # combine A2A: the exact inverse exchange
-        y = jax.lax.all_to_all(y, ep_axis, split_axis=1,
-                               concat_axis=0, tiled=True)
-        out = _group_combine(y.reshape(E * capacity, d), flat_slot,
-                             combine_w, T, k)
-        return out.reshape(x_l.shape), jax.lax.pmean(aux, ep_axis)
+        def shard_fn(router_w, wg, wu, wd, x_l):
+            T = x_l.shape[0] * x_l.shape[1]
+            x2d = x_l.reshape(T, d)
+            buckets, flat_slot, combine_w, aux = _group_dispatch(
+                x2d, router_w, E, k, capacity)
+            # dispatch A2A: keep E/n experts, gather every rank's C slots
+            b = jax.lax.all_to_all(buckets, ep_axis, split_axis=0,
+                                   concat_axis=1, tiled=True)
+            g = jnp.einsum("ecd,edf->ecf", b, wg)
+            u = jnp.einsum("ecd,edf->ecf", b, wu)
+            h = swiglu(g, u)
+            y = jnp.einsum("ecf,efd->ecd", h, wd)
+            # combine A2A: the exact inverse exchange
+            y = jax.lax.all_to_all(y, ep_axis, split_axis=1,
+                                   concat_axis=0, tiled=True)
+            out = _group_combine(y.reshape(E * capacity, d), flat_slot,
+                                 combine_w, T, k)
+            return out.reshape(x_l.shape), jax.lax.pmean(aux, ep_axis)
 
-    fn = jax.shard_map(shard_fn, mesh=mesh,
-                       in_specs=(P(), P(ep_axis), P(ep_axis), P(ep_axis),
-                                 P(ep_axis)),
-                       out_specs=(P(ep_axis), P()),
-                       check_vma=False)
-    out, aux = fn(_v(params["router"]), _v(params["w_gate"]),
-                  _v(params["w_up"]), _v(params["w_down"]), x)
+        fn = jax.shard_map(shard_fn, mesh=mesh,
+                           in_specs=(P(), P(ep_axis), P(ep_axis), P(ep_axis),
+                                     P(ep_axis)),
+                           out_specs=(P(ep_axis), P()),
+                           check_vma=False)
+        out, aux = fn(_v(params["router"]), _v(params["w_gate"]),
+                      _v(params["w_up"]), _v(params["w_down"]), x)
 
-    if cfg.moe_dense_ff:
-        dn = params["dense"]
-        x2d = x.reshape(-1, d)
-        dense = swiglu(x2d @ _v(dn["w_gate"]),
-                       x2d @ _v(dn["w_up"])) @ _v(dn["w_down"])
-        out = out + dense.reshape(B, S, d)
-    return out, aux
+        if cfg.moe_dense_ff:
+            dn = params["dense"]
+            x2d = x.reshape(-1, d)
+            dense = swiglu(x2d @ _v(dn["w_gate"]),
+                           x2d @ _v(dn["w_up"])) @ _v(dn["w_down"])
+            out = out + dense.reshape(B, S, d)
+        return out, aux
 
 
 def _v(p):

@@ -205,46 +205,52 @@ def ssd_reference(x, dt, A, Bmat, Cmat, initial_state=None, return_state=False):
 def mamba2_forward(params, u, cfg, *, chunk: int = 128,
                    state: SSMState | None = None, return_state: bool = False):
     """Full Mamba2 mixer.  u: (B, S, d_model) → (B, S, d_model)."""
-    B, S, d = u.shape
-    H, hd, N, G = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state, cfg.ssm_groups
-    di = cfg.d_inner
+    with jax.named_scope("ssm"):
+        B, S, d = u.shape
+        H, hd = cfg.ssm_heads, cfg.ssm_headdim
+        N, G = cfg.ssm_state, cfg.ssm_groups
+        di = cfg.d_inner
 
-    zxbcdt = u @ params["in_proj"]
-    z, xBC, dt = _split_in_proj(zxbcdt, cfg)
-    lag = state.conv if state is not None else None
-    xBC, new_lag = _causal_conv(xBC, params["conv_w"], params["conv_b"], lag)
-    x = xBC[..., :di].reshape(B, S, H, hd)
-    Bmat = xBC[..., di:di + G * N].reshape(B, S, G, N)
-    Cmat = xBC[..., di + G * N:].reshape(B, S, G, N)
-    A = -jnp.exp(params["a_log"].astype(jnp.float32))
-    dt = jax.nn.softplus(dt.astype(jnp.float32) +
-                         params["dt_bias"].astype(jnp.float32))  # (B,S,H)
+        zxbcdt = u @ params["in_proj"]
+        z, xBC, dt = _split_in_proj(zxbcdt, cfg)
+        lag = state.conv if state is not None else None
+        xBC, new_lag = _causal_conv(xBC, params["conv_w"], params["conv_b"],
+                                    lag)
+        x = xBC[..., :di].reshape(B, S, H, hd)
+        Bmat = xBC[..., di:di + G * N].reshape(B, S, G, N)
+        Cmat = xBC[..., di + G * N:].reshape(B, S, G, N)
+        A = -jnp.exp(params["a_log"].astype(jnp.float32))
+        dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                             params["dt_bias"].astype(jnp.float32))  # (B,S,H)
 
-    h0 = state.h if state is not None else None
-    if S == 1 and state is not None:
-        # O(1) decode recurrence
-        decay = jnp.exp(dt[:, 0] * A)                          # (B,H)
-        Bh = jnp.repeat(Bmat[:, 0], H // G, axis=1)
-        Ch = jnp.repeat(Cmat[:, 0], H // G, axis=1)
-        upd = jnp.einsum("bh,bhd,bhn->bhdn", dt[:, 0],
-                         x[:, 0].astype(jnp.float32), Bh.astype(jnp.float32))
-        h = state.h.astype(jnp.float32) * decay[..., None, None] + upd
-        y = jnp.einsum("bhn,bhdn->bhd", Ch.astype(jnp.float32), h)[:, None]
-        y = y.astype(u.dtype)
-        hT = h
-    else:
-        y, hT = ssd_chunked(x, dt, A, Bmat, Cmat, chunk=chunk,
-                            initial_state=h0, return_state=True)
+        h0 = state.h if state is not None else None
+        with jax.named_scope("ssd"):
+            if S == 1 and state is not None:
+                # O(1) decode recurrence
+                decay = jnp.exp(dt[:, 0] * A)                          # (B,H)
+                Bh = jnp.repeat(Bmat[:, 0], H // G, axis=1)
+                Ch = jnp.repeat(Cmat[:, 0], H // G, axis=1)
+                upd = jnp.einsum("bh,bhd,bhn->bhdn", dt[:, 0],
+                                 x[:, 0].astype(jnp.float32),
+                                 Bh.astype(jnp.float32))
+                h = state.h.astype(jnp.float32) * decay[..., None, None] + upd
+                y = jnp.einsum("bhn,bhdn->bhd", Ch.astype(jnp.float32),
+                               h)[:, None]
+                y = y.astype(u.dtype)
+                hT = h
+            else:
+                y, hT = ssd_chunked(x, dt, A, Bmat, Cmat, chunk=chunk,
+                                    initial_state=h0, return_state=True)
 
-    y = y + x * params["d_skip"].astype(u.dtype)[None, None, :, None]
-    y = y.reshape(B, S, di)
-    # gated RMSNorm (mamba2's norm-before-out)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 params["norm_g"], cfg.norm_eps)
-    out = y @ params["out_proj"]
-    if return_state:
-        return out, SSMState(h=hT, conv=new_lag)
-    return out
+        y = y + x * params["d_skip"].astype(u.dtype)[None, None, :, None]
+        y = y.reshape(B, S, di)
+        # gated RMSNorm (mamba2's norm-before-out)
+        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                     params["norm_g"], cfg.norm_eps)
+        out = y @ params["out_proj"]
+        if return_state:
+            return out, SSMState(h=hT, conv=new_lag)
+        return out
 
 
 def init_ssm_state(cfg, batch: int, dtype=jnp.float32) -> SSMState:

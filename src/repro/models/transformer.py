@@ -11,6 +11,13 @@ Hybrid (zamba2) structure: ``num_layers`` Mamba2 blocks; after every
 weights, applied num_layers/attn_every times, each application with its own
 KV cache slice — weights shared, activations not).
 
+Each block kind runs under one ``jax.named_scope`` (``embed``, ``ssm`` with
+``ssd`` inside it, ``attention``, ``mlp``, ``moe``, ``lm_head``, ``loss``;
+``optimizer`` in ``train.optim``): the scope is metadata on the compiled
+ops, so a profile's device time names its block.  The hybrid group loop's
+slicing and stacking in ``_scan_blocks`` is left unscoped on purpose, so
+that what XLA makes of it shows apart from the blocks.
+
 Entry points:
   * ``init``          — Box-tree of parameters.
   * ``loss_fn``       — (params, batch) → (loss, metrics); full causal LM.
@@ -107,10 +114,11 @@ def init(key, cfg: ModelConfig, dtype=jnp.float32):
 def _embed_inputs(params, cfg, batch, constrain):
     """Token (+ patch) embedding.  Returns (x, positions)."""
     tokens = batch["tokens"]
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.family == "vlm" and "patch_embeds" in batch:
-        pe = batch["patch_embeds"].astype(x.dtype) @ params["mm_proj"]
-        x = jnp.concatenate([pe, x], axis=1)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.family == "vlm" and "patch_embeds" in batch:
+            pe = batch["patch_embeds"].astype(x.dtype) @ params["mm_proj"]
+            x = jnp.concatenate([pe, x], axis=1)
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     return constrain(x), positions
@@ -236,6 +244,15 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
             new_cross if (has_cross and mode == "prefill") else None, aux)
 
 
+def _lm_head(params, cfg, x, constrain):
+    """Final norm and the vocabulary projection: (B, S, d) → (B, S, V)."""
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        return constrain(x @ head, "logits")
+
+
 # --------------------------------------------------------------------------
 # training loss
 # --------------------------------------------------------------------------
@@ -250,16 +267,15 @@ def loss_fn(params, batch, cfg: ModelConfig, pcfg: ParallelConfig,
     x, _, _, _, _, aux = _scan_blocks(params, cfg, pcfg, x, positions,
                                       constrain, mode="train", enc_out=enc_out,
                                       layer_constrain=layer_constrain)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = constrain(x @ head, "logits")
+    logits = _lm_head(params, cfg, x, constrain)
     labels = batch["labels"]
     if cfg.family == "vlm" and "patch_embeds" in batch:
         # image positions don't predict tokens
         P = batch["patch_embeds"].shape[1]
         pad = jnp.full((labels.shape[0], P), -1, labels.dtype)
         labels = jnp.concatenate([pad, labels], axis=1)
-    loss, count = softmax_cross_entropy(logits, labels, cfg.vocab_size)
+    with jax.named_scope("loss"):
+        loss, count = softmax_cross_entropy(logits, labels, cfg.vocab_size)
     total = loss + cfg.router_aux_weight * aux
     return total, {"loss": loss, "aux_loss": aux, "tokens": count}
 
@@ -299,9 +315,7 @@ def prefill(params, batch, cfg, pcfg, cache_len: int,
     x, kv, ssm, shared, cross, _ = _scan_blocks(
         params, cfg, pcfg, x, positions, constrain, mode="prefill",
         enc_out=enc_out, cache_len=cache_len, layer_constrain=layer_constrain)
-    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = constrain(x @ head, "logits")
+    logits = _lm_head(params, cfg, x[:, -1:], constrain)
     state = DecodeState(kv=kv, ssm=ssm, shared_kv=shared, cross_kv=cross,
                         index=jnp.array(batch["tokens"].shape[1] +
                                         (batch.get("patch_embeds").shape[1]
@@ -316,7 +330,8 @@ def decode_step(params, tokens, state: DecodeState, cfg, pcfg,
                 layer_constrain=lambda bp: bp
                 ) -> Tuple[jnp.ndarray, DecodeState]:
     """One decode step.  tokens: (B, 1) int32 → logits (B, V)."""
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     B = x.shape[0]
     positions = jnp.broadcast_to(state.index[None, None], (B, 1)).astype(jnp.int32)
     x, kv, ssm, shared, cross, _ = _scan_blocks(
@@ -324,9 +339,7 @@ def decode_step(params, tokens, state: DecodeState, cfg, pcfg,
         kv=state.kv, ssm=state.ssm, shared_kv=state.shared_kv,
         cross_kv=state.cross_kv, cache_index=state.index,
         layer_constrain=layer_constrain)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = constrain(x @ head, "logits")
+    logits = _lm_head(params, cfg, x, constrain)
     new_state = DecodeState(kv=kv if kv is not None else state.kv,
                             ssm=ssm if ssm is not None else state.ssm,
                             shared_kv=shared if shared is not None else state.shared_kv,

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +54,17 @@ class Engine:
         self.cfg = cfg
         self.pcfg = (pcfg or ParallelConfig()).replace(remat="none")
         self.ecfg = ecfg or EngineConfig()
-        self._prefill = jax.jit(
-            lambda p, b: tfm.prefill(p, b, cfg, self.pcfg,
-                                     self.ecfg.cache_len))
-        self._decode = jax.jit(
-            lambda p, t, s: tfm.decode_step(p, t, s, cfg, self.pcfg))
+        # named functions, so that a profile shows the two programs as
+        # jit_prefill and jit_decode_step
+        def prefill(params, batch):
+            return tfm.prefill(params, batch, cfg, self.pcfg,
+                               self.ecfg.cache_len)
+
+        def decode_step(params, tokens, state):
+            return tfm.decode_step(params, tokens, state, cfg, self.pcfg)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step)
         # per-decode-step wall times of the most recent run_batch (first
         # entry includes the decode jit compile; dryrun --serving drops it)
         self.decode_step_s: List[float] = []
@@ -67,6 +73,10 @@ class Engine:
         # batch's last tokens
         self.prefill_s: float = 0.0
         self.last_logits: Optional[jnp.ndarray] = None
+        # host seconds of the most recent run_batch spent bringing logits
+        # to the host and choosing tokens (spans engine.logits_to_host and
+        # engine.sample)
+        self.sample_s: float = 0.0
 
     def _sample(self, logits: jnp.ndarray, reqs: List[Request],
                 key) -> np.ndarray:
@@ -87,13 +97,36 @@ class Engine:
                 (int(jax.random.key_data(key)[0]), r.uid)).choice(len(p), p=p))
         return out
 
+    def _next_tokens(self, logits: jnp.ndarray, reqs: List[Request], key,
+                     step: Optional[int] = None) -> Tuple[np.ndarray, Any]:
+        """``_sample`` on the host, timed into ``sample_s``: the logits
+        brought over, then (a decode ``step`` folded into ``key`` first)
+        the tokens drawn.  Returns the tokens and the key drawn with."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.logits_to_host"):
+            logits = np.asarray(logits, np.float32)
+        with jax.profiler.TraceAnnotation("engine.sample"):
+            if step is not None:
+                key = jax.random.fold_in(key, step)
+            out = self._sample(logits, reqs, key)
+        self.sample_s += time.perf_counter() - t0
+        return out, key
+
     def run_batch(self, requests: List[Request], seed: int = 0
                   ) -> List[Request]:
-        """Serve one admission batch to completion."""
+        """Serve one admission batch to completion.  A profile shows it as
+        host spans: engine.run_batch around engine.prefill, then per step
+        engine.logits_to_host, engine.sample and engine.decode."""
         if len(requests) > self.ecfg.max_batch:
             raise ValueError("admit at most max_batch requests")
+        with jax.profiler.TraceAnnotation("engine.run_batch"):
+            return self._run_batch(requests, seed)
+
+    def _run_batch(self, requests: List[Request], seed: int
+                   ) -> List[Request]:
         t0 = time.perf_counter()
         self.decode_step_s = []
+        self.sample_s = 0.0
         key = jax.random.PRNGKey(seed)
         B = len(requests)
         plen = max(len(r.prompt) for r in requests)
@@ -101,14 +134,16 @@ class Engine:
         for i, r in enumerate(requests):
             toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
         ts = time.perf_counter()
-        logits, state = self._prefill(self.params, {"tokens": jnp.asarray(toks)})
-        logits.block_until_ready()
+        with jax.profiler.TraceAnnotation("engine.prefill"):
+            logits, state = self._prefill(self.params,
+                                          {"tokens": jnp.asarray(toks)})
+            logits.block_until_ready()
         self.prefill_s = time.perf_counter() - ts
 
         outs: List[List[int]] = [[] for _ in requests]
         done = np.zeros(B, bool)
         max_new = max(r.max_new_tokens for r in requests)
-        next_tok = self._sample(logits, requests, key)
+        next_tok, key = self._next_tokens(logits, requests, key)
         for step in range(max_new):
             for i, r in enumerate(requests):
                 if not done[i]:
@@ -120,12 +155,13 @@ class Engine:
             if done.all():
                 break
             ts = time.perf_counter()
-            logits, state = self._decode(
-                self.params, jnp.asarray(next_tok)[:, None], state)
-            logits.block_until_ready()
+            with jax.profiler.TraceAnnotation("engine.decode"):
+                logits, state = self._decode(
+                    self.params, jnp.asarray(next_tok)[:, None], state)
+                logits.block_until_ready()
             self.decode_step_s.append(time.perf_counter() - ts)
-            key = jax.random.fold_in(key, step)
-            next_tok = self._sample(logits, requests, key)
+            next_tok, key = self._next_tokens(logits, requests, key,
+                                              step)
 
         dt = time.perf_counter() - t0
         self.last_logits = logits

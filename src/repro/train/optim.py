@@ -116,62 +116,64 @@ def global_norm(tree):
 def adam_update(params, grads, state: AdamState, ocfg: OptimConfig
                 ) -> Tuple[Any, AdamState, dict]:
     """One AdamW step.  Returns (new_params, new_state, metrics)."""
-    step = state.step + 1
-    lr = lr_schedule(step, ocfg)
-    gnorm = global_norm(grads)
-    clip = jnp.minimum(1.0, ocfg.grad_clip / jnp.maximum(gnorm, 1e-12)) \
-        if ocfg.grad_clip else 1.0
+    with jax.named_scope("optimizer"):
+        step = state.step + 1
+        lr = lr_schedule(step, ocfg)
+        gnorm = global_norm(grads)
+        clip = jnp.minimum(1.0, ocfg.grad_clip / jnp.maximum(gnorm, 1e-12)) \
+            if ocfg.grad_clip else 1.0
 
-    b1, b2 = ocfg.b1, ocfg.b2
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
+        b1, b2 = ocfg.b1, ocfg.b2
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
 
-    is_q = lambda x: isinstance(x, QTensor)
+        is_q = lambda x: isinstance(x, QTensor)
 
-    def leaf_core(p, g, m, v, mw):
-        g = g.astype(jnp.float32) * clip
-        mf = _decode_moment(m)
-        vf = _decode_moment(v)
-        mf = b1 * mf + (1 - b1) * g
-        vf = b2 * vf + (1 - b2) * jnp.square(g)
-        upd = (mf / bc1) / (jnp.sqrt(vf / bc2) + ocfg.eps)
-        base = mw if mw is not None else p.astype(jnp.float32)
-        new_master = base - lr * (upd + ocfg.weight_decay * base)
-        return (new_master.astype(p.dtype),
-                _encode_moment(mf, ocfg.moments_dtype, True),
-                _encode_moment(vf, ocfg.moments_dtype, False),
-                new_master if mw is not None else None)
+        def leaf_core(p, g, m, v, mw):
+            g = g.astype(jnp.float32) * clip
+            mf = _decode_moment(m)
+            vf = _decode_moment(v)
+            mf = b1 * mf + (1 - b1) * g
+            vf = b2 * vf + (1 - b2) * jnp.square(g)
+            upd = (mf / bc1) / (jnp.sqrt(vf / bc2) + ocfg.eps)
+            base = mw if mw is not None else p.astype(jnp.float32)
+            new_master = base - lr * (upd + ocfg.weight_decay * base)
+            return (new_master.astype(p.dtype),
+                    _encode_moment(mf, ocfg.moments_dtype, True),
+                    _encode_moment(vf, ocfg.moments_dtype, False),
+                    new_master if mw is not None else None)
 
-    # Huge stacked leaves (MoE expert banks: Gbytes of fp32 intermediates)
-    # are updated slice-by-slice over the leading 'layers' dim so the fp32
-    # temporaries stay one-layer-sized.
-    SCAN_THRESHOLD = 1 << 62   # disabled: broke XLA aliasing (measured +16GiB)
+        # Huge stacked leaves (MoE expert banks: Gbytes of fp32 intermediates)
+        # are updated slice-by-slice over the leading 'layers' dim so the fp32
+        # temporaries stay one-layer-sized.
+        # disabled: broke XLA aliasing (measured +16GiB)
+        SCAN_THRESHOLD = 1 << 62
 
-    def leaf(p, g, m, v, mw):
-        if p.size <= SCAN_THRESHOLD or p.ndim < 2:
-            return leaf_core(p, g, m, v, mw)
-        if mw is None:
+        def leaf(p, g, m, v, mw):
+            if p.size <= SCAN_THRESHOLD or p.ndim < 2:
+                return leaf_core(p, g, m, v, mw)
+            if mw is None:
+                def body(_, xs):
+                    np_, nm, nv, _none = leaf_core(*xs, None)
+                    return None, (np_, nm, nv)
+                _, (np_, nm, nv) = jax.lax.scan(body, None, (p, g, m, v))
+                return np_, nm, nv, None
             def body(_, xs):
-                np_, nm, nv, _none = leaf_core(*xs, None)
-                return None, (np_, nm, nv)
-            _, (np_, nm, nv) = jax.lax.scan(body, None, (p, g, m, v))
-            return np_, nm, nv, None
-        def body(_, xs):
-            return None, leaf_core(*xs)
-        _, (np_, nm, nv, nmw) = jax.lax.scan(body, None, (p, g, m, v, mw))
-        return np_, nm, nv, nmw
+                return None, leaf_core(*xs)
+            _, (np_, nm, nv, nmw) = jax.lax.scan(body, None, (p, g, m, v, mw))
+            return np_, nm, nv, nmw
 
-    p_flat, treedef = jax.tree.flatten(params)
-    g_flat = treedef.flatten_up_to(grads)
-    m_flat = treedef.flatten_up_to(state.m)
-    v_flat = treedef.flatten_up_to(state.v)
-    mw_flat = (treedef.flatten_up_to(state.master)
-               if state.master is not None else [None] * len(p_flat))
-    results = [leaf(p, g, m, v, mw) for p, g, m, v, mw
-               in zip(p_flat, g_flat, m_flat, v_flat, mw_flat)]
-    unflat = lambda i: jax.tree.unflatten(treedef, [r[i] for r in results])
-    new_state = AdamState(
-        step=step,
-        master=unflat(3) if state.master is not None else None,
-        m=unflat(1), v=unflat(2))
-    return unflat(0), new_state, {"grad_norm": gnorm, "lr": lr}
+        p_flat, treedef = jax.tree.flatten(params)
+        g_flat = treedef.flatten_up_to(grads)
+        m_flat = treedef.flatten_up_to(state.m)
+        v_flat = treedef.flatten_up_to(state.v)
+        mw_flat = (treedef.flatten_up_to(state.master)
+                   if state.master is not None else [None] * len(p_flat))
+        results = [leaf(p, g, m, v, mw) for p, g, m, v, mw
+                   in zip(p_flat, g_flat, m_flat, v_flat, mw_flat)]
+        unflat = lambda i: jax.tree.unflatten(treedef, [r[i] for r in results])
+        new_state = AdamState(
+            step=step,
+            master=unflat(3) if state.master is not None else None,
+            m=unflat(1), v=unflat(2))
+        return unflat(0), new_state, {"grad_norm": gnorm, "lr": lr}

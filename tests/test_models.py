@@ -6,6 +6,7 @@ and no NaNs.  Full configs are exercised only via the dry-run.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -208,3 +209,111 @@ def test_ssd_state_carry():
                      chunk=8, initial_state=st)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(full), atol=2e-4, rtol=1e-3)
+
+
+# --------------------------------------------------------------------------
+# block scopes: op_name metadata only
+# --------------------------------------------------------------------------
+
+SCOPES = {"embed", "ssm", "ssd", "attention", "mlp", "moe", "lm_head",
+          "loss", "optimizer"}
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+METADATA = re.compile(r",? metadata=\{[^}]*\}")
+MATMUL = re.compile(r"\s(dot|convolution)\(")
+NAME = re.compile(r"%[\w.-]+")
+
+
+def _lowered(program):
+    """(jitted program, its arguments) of one scoped program at a tiny
+    size: zamba2 prefill and decode, a mixtral prefill (moe), a mamba2
+    train step with block remat (loss, optimizer, the backward pass)."""
+    from repro.train.optim import OptimConfig, adam_update, init_adam
+    arch, kind = program.split(":")
+    cfg = get_config(arch).reduced()
+    params, _ = split(tfm.init(KEY, cfg, dtype=jnp.bfloat16))
+    toks = jax.random.randint(KEY, (2, 16), 0, cfg.vocab_size)
+    if kind == "prefill":
+        def prefill(p, t):
+            return tfm.prefill(p, {"tokens": t}, cfg, PCFG, 32)
+        return jax.jit(prefill), (params, toks)
+    if kind == "decode":
+        state = jax.eval_shape(lambda p, t: tfm.prefill(
+            p, {"tokens": t}, cfg, PCFG, 32)[1], params, toks)
+
+        def decode_step(p, t, s):
+            return tfm.decode_step(p, t, s, cfg, PCFG)
+        return jax.jit(decode_step), (params, toks[:, :1], state)
+    pcfg = ParallelConfig(remat="block")
+    ocfg = OptimConfig()
+
+    def train_step(p, opt, batch):
+        (_, m), g = jax.value_and_grad(
+            lambda p: tfm.loss_fn(p, batch, cfg, pcfg), has_aux=True)(p)
+        p, opt, _ = adam_update(p, g, opt, ocfg)
+        return p, opt, m["loss"]
+    return jax.jit(train_step), (params, init_adam(params, ocfg),
+                                 {"tokens": toks, "labels": toks})
+
+
+def _scopes(op_name):
+    """Scope names among an op_name's path components, wrappers such as
+    jvp(...) or transpose(...) stripped."""
+    return SCOPES & set(re.findall(r"\w+", op_name))
+
+
+SCOPED_PROGRAMS = {
+    "zamba2-2.7b:prefill": {"embed", "ssm", "ssd", "attention", "mlp",
+                            "lm_head"},
+    "zamba2-2.7b:decode": {"embed", "ssm", "ssd", "attention", "mlp",
+                           "lm_head"},
+    "mixtral-8x7b:prefill": {"embed", "attention", "moe", "lm_head"},
+    "mamba2-1.3b:train": {"embed", "ssm", "ssd", "lm_head", "loss",
+                          "optimizer"},
+}
+
+
+@pytest.mark.parametrize("program", sorted(SCOPED_PROGRAMS))
+def test_block_scopes_in_compiled_hlo(program):
+    """Every block kind the program runs names its ops in the compiled
+    HLO, and every matmul jax writes lies in some scope.  Matmuls that
+    XLA's CPU passes build anew carry no metadata at all (the SSD
+    einsums' batched dots at these shapes); they are not jax's to name."""
+    fn, args = _lowered(program)
+    lowered = fn.lower(*args)
+    names = OP_NAME.findall(lowered.compile().as_text())
+    found = set().union(*map(_scopes, names))
+    assert found == SCOPED_PROGRAMS[program]
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    matmuls = [line for line in text.splitlines() if MATMUL.search(line)]
+    assert matmuls
+    for line in matmuls:
+        name = OP_NAME.search(line)
+        assert name and _scopes(name.group(1)), line
+
+
+@pytest.mark.parametrize("program", sorted(SCOPED_PROGRAMS))
+def test_block_scopes_leave_compiled_ops_unchanged(program, monkeypatch):
+    """The compiled program runs the same ops in the same order with the
+    scopes as without them: its text is the same once metadata and the
+    source tables are stripped and every instruction and computation is
+    renamed by first appearance (XLA names instructions from their
+    source locations, which the scopes are part of)."""
+    import contextlib
+
+    def compiled():
+        jax.clear_caches()
+        fn, args = _lowered(program)
+        lines = fn.lower(*args).compile().as_text().splitlines()
+        first = next(i for i, line in enumerate(lines)
+                     if line.startswith(("%", "ENTRY")))
+        names = {}
+        return NAME.sub(
+            lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+            METADATA.sub("", "\n".join(lines[:1] + lines[first:])))
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert "op_name" not in scoped
+    assert scoped == bare

@@ -303,6 +303,42 @@ def test_engine_serves_batch_greedy_matches_decode():
     assert [r.output for r in done] == [r.output for r in done2]
 
 
+def test_engine_spans_leave_tokens_alone(tmp_path):
+    """The same seed serves the same tokens with the profiler recording
+    the Engine's spans and without it; ``sample_s`` lies within the
+    batch, and the trace holds every span the Engine opens."""
+    from jax.profiler import ProfileData
+    from repro.serve.engine import Engine, EngineConfig, Request
+    cfg = get_config("zamba2-2.7b").reduced()
+    params, _ = split(tfm.init(KEY, cfg))
+    eng = Engine(params, cfg, ecfg=EngineConfig(max_batch=4, cache_len=32))
+
+    def serve():
+        reqs = [Request(uid=i, prompt=[1 + i, 2, 3, 4], max_new_tokens=5,
+                        temperature=0.7 * (i % 2), top_k=8 * (i % 2))
+                for i in range(4)]
+        t0 = time.perf_counter()
+        eng.run_batch(reqs, seed=2_000_000_011)
+        wall = time.perf_counter() - t0
+        assert 0.0 <= eng.sample_s <= wall
+        return [r.output for r in reqs]
+
+    plain = serve()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced = serve()
+    finally:
+        jax.profiler.stop_trace()
+    assert traced == plain
+    path, = tmp_path.rglob("*.xplane.pb")
+    names = {e.name for p in ProfileData.from_file(str(path)).planes
+             for line in p.lines for e in line.events}
+    assert {"engine.run_batch", "engine.prefill", "engine.decode",
+            "engine.logits_to_host", "engine.sample"} <= names
+    # the two programs by their functions' names, not as lambdas
+    assert {"PjitFunction(prefill)", "PjitFunction(decode_step)"} <= names
+
+
 # --------------------------------------------------------------------------
 # trainer loop (fast end-to-end: init → train → checkpoint → resume)
 # --------------------------------------------------------------------------
