@@ -10,7 +10,8 @@ Design notes
 * All softmax statistics are fp32; matmuls run in the compute dtype (bf16).
 * Chunking is a double ``lax.scan``: outer over query blocks, inner over KV
   blocks with running (max, denom) online-softmax state — O(S·chunk) memory
-  instead of O(S²), which is what lets ``prefill_32k`` fit HBM.
+  instead of O(S²), which is what lets ``prefill_32k`` fit HBM.  The inner
+  step is checkpointed, so the backward pass keeps that bound too.
 * Causal + sliding-window masks are computed from block offsets, and KV
   blocks that are fully masked are *skipped for memory purposes only* (the
   scan still visits them — XLA hoists the constant mask; on TPU the Pallas
@@ -160,7 +161,10 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             return (m_new, l_new, acc_new), None
 
         ks = (jnp.arange(nk), jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0))
-        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, acc0), ks)
+        # the backward pass recomputes each block's scores rather than
+        # keeping every block's (stacked: O(S²)) as residuals
+        (m, l, acc), _ = jax.lax.scan(jax.checkpoint(kv_step), (m0, l0, acc0),
+                                      ks)
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return None, out.astype(q.dtype)
 

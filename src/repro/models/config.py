@@ -247,6 +247,7 @@ class ParallelConfig:
     attn_sharding: str = "heads"                  # heads | context
     scan_layers: bool = True
     remat: str = "block"                          # none | block | full
+    #   (under scan_layers the hybrid's shared block is always recomputed)
     grad_sync: str = "hierarchical"               # flat | hierarchical | compressed
     seq_shard: bool = True                        # SP: shard seq dim of activations
     moe_ep_axis: str = ""                         # "" = TP-only MoE

@@ -91,9 +91,13 @@ def apply_attention(p, cfg, pcfg, x, *, positions, mode: str = "train",
                     cache: Optional[KVCache] = None, cache_index=None,
                     cache_len: Optional[int] = None, kv_x=None,
                     causal: bool = True, window: int = 0,
-                    constrain=lambda t, kind="residual": t,
+                    constrain=lambda t, kind="residual": t, cache_slot=None,
                     ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
-    """Unified attention. Returns (out, new_cache)."""
+    """Unified attention. Returns (out, new_cache).
+
+    ``cache_slot`` (a possibly traced index) names this call's entry in a
+    cache stacked on a leading axis: decode writes the new K/V into that
+    entry in place and attends over it, prefill writes its cache there."""
     with jax.named_scope("attention"):
         B, S, d = x.shape
         cross = kv_x is not None
@@ -119,13 +123,17 @@ def apply_attention(p, cfg, pcfg, x, *, positions, mode: str = "train",
 
         if mode == "decode":
             # write new K/V at cache_index (rolling slot for SWA buffers)
-            S_cache = cache.k.shape[1]
+            S_cache = cache.k.shape[-3]
             write_pos = cache_index % S_cache if window else cache_index
-            kc = _write_cache(cache.k, k, write_pos)
-            vc = _write_cache(cache.v, v, write_pos)
+            new_cache = KVCache(_write_cache(cache.k, k, write_pos, cache_slot),
+                                _write_cache(cache.v, v, write_pos, cache_slot))
+            kc, vc = new_cache
+            if cache_slot is not None:
+                kc, vc = (jax.lax.dynamic_index_in_dim(a, cache_slot,
+                                                       keepdims=False)
+                          for a in new_cache)
             valid = jnp.minimum(cache_index + 1, S_cache)
             out = decode_attention(q, kc, vc, jnp.broadcast_to(valid, (B,)))
-            new_cache = KVCache(kc, vc)
         else:
             if cross:
                 out = chunked_attention(q, k, v, causal=False,
@@ -141,15 +149,22 @@ def apply_attention(p, cfg, pcfg, x, *, positions, mode: str = "train",
                 new_cache = _build_cache(k, v,
                                          cache_len=cache_len or k.shape[1],
                                          window=window)
+                if cache_slot is not None:
+                    new_cache = KVCache(*(
+                        jax.lax.dynamic_update_index_in_dim(
+                            a, n.astype(a.dtype), cache_slot, 0)
+                        for a, n in zip(cache, new_cache)))
         B2, S2 = out.shape[:2]
         return out.reshape(B2, S2, -1) @ p["wo"], new_cache
 
 
-def _write_cache(buf, kv, pos):
-    """dynamic_update_slice along seq dim (pos may be traced)."""
-    return jax.lax.dynamic_update_slice(
-        buf, kv.astype(buf.dtype),
-        (0, pos) + (0,) * (buf.ndim - 2))
+def _write_cache(buf, kv, pos, slot=None):
+    """dynamic_update_slice along seq dim (pos may be traced); into entry
+    ``slot`` of a leading stacked axis when one is given."""
+    start = (0, pos) + (0,) * (kv.ndim - 2)
+    if slot is not None:
+        kv, start = kv[None], (slot,) + start
+    return jax.lax.dynamic_update_slice(buf, kv.astype(buf.dtype), start)
 
 
 def _build_cache(k, v, cache_len: int, window: int = 0) -> KVCache:
@@ -222,14 +237,15 @@ def apply_attn_block(p, cfg, pcfg, x, *, positions, mode="train",
                      cache: Optional[KVCache] = None, cache_index=None,
                      cache_len: Optional[int] = None,
                      cross_cache: Optional[KVCache] = None, enc_out=None,
-                     causal=True, constrain=lambda t, kind="residual": t):
+                     causal=True, constrain=lambda t, kind="residual": t,
+                     cache_slot=None):
     """Returns (x, new_cache, new_cross_cache, aux_loss)."""
     window = cfg.sliding_window
     h, new_cache = apply_attention(
         p["attn"], cfg, pcfg, rms_norm(x, p["ln1"], cfg.norm_eps),
         positions=positions, mode=mode, cache=cache, cache_index=cache_index,
         cache_len=cache_len, causal=causal, window=window,
-        constrain=constrain)
+        constrain=constrain, cache_slot=cache_slot)
     x = constrain(x + h)
     new_cross = cross_cache
     if "cross" in p:

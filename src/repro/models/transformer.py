@@ -9,14 +9,16 @@ around the block body.
 Hybrid (zamba2) structure: ``num_layers`` Mamba2 blocks; after every
 ``attn_every`` of them, a single *shared* attention block (one set of
 weights, applied num_layers/attn_every times, each application with its own
-KV cache slice — weights shared, activations not).
+KV cache slice — weights shared, activations not).  It runs as the ssm
+family does, one scan over the stacked Mamba2 layers, with the shared block
+inside the scan body under a ``lax.cond`` on the layer index.
 
 Each block kind runs under one ``jax.named_scope`` (``embed``, ``ssm`` with
 ``ssd`` inside it, ``attention``, ``mlp``, ``moe``, ``lm_head``, ``loss``;
 ``optimizer`` in ``train.optim``): the scope is metadata on the compiled
-ops, so a profile's device time names its block.  The hybrid group loop's
-slicing and stacking in ``_scan_blocks`` is left unscoped on purpose, so
-that what XLA makes of it shows apart from the blocks.
+ops, so a profile's device time names its block.  The scan's own slicing
+and state updates are left unscoped on purpose, so that what XLA makes of
+them shows apart from the blocks.
 
 Entry points:
   * ``init``          — Box-tree of parameters.
@@ -33,6 +35,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import ModelConfig, ParallelConfig
 from .layers import (KVCache, apply_attn_block, init_attn_block)
@@ -125,6 +128,9 @@ def _embed_inputs(params, cfg, batch, constrain):
 
 
 def _maybe_remat(fn, pcfg: ParallelConfig):
+    """Checkpoint one layer by ``pcfg.remat``.  Under the layer scan the
+    hybrid's shared block is recomputed in the backward pass whatever
+    ``remat`` says (``_scan_blocks``)."""
     if pcfg.remat == "none":
         return fn
     policy = (jax.checkpoint_policies.nothing_saveable if pcfg.remat == "full"
@@ -161,9 +167,39 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
         return carry, stacked
 
     if is_ssm_family:
+        # one scan over the stacked Mamba2 layers; the hybrid's shared block
+        # runs inside it after every ``attn_every``-th layer, so the stacked
+        # weights and state are scan operands whole, never sliced by group
+        every = cfg.attn_every if cfg.family == "hybrid" else 0
+
+        def shared_block(h, skv, g):
+            """Application g of the shared attention+MLP block; its KV is
+            entry g of the carried (groups, ...) cache."""
+            h, skv, _, _ = apply_attn_block(
+                params["shared_attn"], cfg, pcfg, h, positions=positions,
+                mode=mode, cache=skv, cache_index=cache_index,
+                cache_len=cache_len, constrain=constrain, cache_slot=g)
+            return h, skv
+
+        def shared_after(h, skv, i):
+            """The shared block after layer i, where layer i ends a group."""
+            if isinstance(i, (int, np.integer)):   # unrolled: a static if
+                if i % every == every - 1:
+                    h, skv = shared_block(h, skv, i // every)
+                return h, skv
+            return jax.lax.cond(i % every == every - 1, shared_block,
+                                lambda h, skv, g: (h, skv), h, skv, i // every)
+        if every and pcfg.scan_layers:
+            # autodiff keeps one residual slot a layer under the scan: what
+            # the shared block saved would be stacked num_layers times, zero
+            # in all but one layer of attn_every.  Keep only its input and
+            # recompute it in the backward pass.
+            shared_after = jax.checkpoint(
+                shared_after, policy=jax.checkpoint_policies.nothing_saveable)
+
         def body(carry, xs):
-            h, = carry
-            bp, st = xs
+            h, skv = carry
+            bp, st, i = xs
             # re-pin the per-layer slice to its stored sharding so FSDP
             # all-gathers happen inside the loop body, not on the whole stack
             bp = layer_constrain(bp)
@@ -178,39 +214,23 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
                 return constrain(h + out), new_st
             run = _maybe_remat(run, pcfg)
             h, new_st = run(h, bp, st)
-            return (h,), new_st
+            if every:
+                h, skv = shared_after(h, skv, i)
+            return (h, skv), new_st
 
+        skv = None
+        if every and mode == "decode":
+            skv = shared_kv
+        elif every and mode == "prefill":
+            # each application writes its prompt's KV into a zero buffer
+            skv = _shared_kv_zeros(cfg, x.shape[0], cache_len or x.shape[1],
+                                   x.dtype)
         scan_ssm = ssm if mode == "decode" else None
-        if cfg.family == "hybrid":
-            groups = L // cfg.attn_every
-            gp = jax.tree.map(lambda a: a.reshape(groups, cfg.attn_every, *a.shape[1:]),
-                              params["blocks"])
-            gs = (jax.tree.map(lambda a: a.reshape(groups, cfg.attn_every, *a.shape[1:]),
-                               scan_ssm) if scan_ssm is not None else None)
-            new_ssm_groups, new_shared = [], []
-            aux = jnp.zeros((), jnp.float32)
-            for g in range(groups):
-                bg = jax.tree.map(lambda a: a[g], gp)
-                sg = jax.tree.map(lambda a: a[g], gs) if gs is not None else None
-                (x,), sg_new = maybe_scan(body, (x,), (bg, sg), cfg.attn_every)
-                new_ssm_groups.append(sg_new)
-                skv = (jax.tree.map(lambda a: a[g], shared_kv)
-                       if shared_kv is not None else None)
-                x, nkv, _, a = apply_attn_block(
-                    params["shared_attn"], cfg, pcfg, x, positions=positions,
-                    mode=mode, cache=skv, cache_index=cache_index,
-                    cache_len=cache_len, constrain=constrain)
-                aux = aux + a
-                new_shared.append(nkv)
-            new_ssm = (jax.tree.map(lambda *xs: jnp.concatenate(xs), *new_ssm_groups)
-                       if mode != "train" else None)
-            new_shared_kv = (jax.tree.map(lambda *xs: jnp.stack(xs), *new_shared)
-                            if mode != "train" else None)
-            return x, None, new_ssm, new_shared_kv, None, aux
-
-        (x,), new_ssm = maybe_scan(body, (x,), (params["blocks"], scan_ssm), L)
-        return (x, None, new_ssm if mode != "train" else None, None, None,
-                jnp.zeros((), jnp.float32))
+        index = np.arange(L, dtype=np.int32) if every else None
+        (x, skv), new_ssm = maybe_scan(body, (x, skv),
+                                       (params["blocks"], scan_ssm, index), L)
+        # train carries no state: new_ssm and skv are None there
+        return x, None, new_ssm, skv, None, jnp.zeros((), jnp.float32)
 
     # --- attention families ------------------------------------------------
     has_cross = cfg.family == "audio"
@@ -284,6 +304,15 @@ def loss_fn(params, batch, cfg: ModelConfig, pcfg: ParallelConfig,
 # serving
 # --------------------------------------------------------------------------
 
+def _shared_kv_zeros(cfg: ModelConfig, batch: int, cache_len: int,
+                     dtype) -> KVCache:
+    """The hybrid's shared-block KV cache, one entry per application."""
+    eff_len = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
+    z = jnp.zeros((cfg.num_layers // cfg.attn_every, batch, eff_len,
+                   cfg.n_kv_heads, cfg.head_dim), dtype)
+    return KVCache(z, z)
+
+
 def init_decode_state(cfg: ModelConfig, batch: int, cache_len: int,
                       dtype=jnp.bfloat16) -> DecodeState:
     """Allocate the decode state for a given cache length."""
@@ -293,9 +322,7 @@ def init_decode_state(cfg: ModelConfig, batch: int, cache_len: int,
     if cfg.family in ("ssm", "hybrid"):
         ssm = jax.vmap(lambda _: init_ssm_state(cfg, batch, dtype))(jnp.arange(L))
         if cfg.family == "hybrid":
-            groups = L // cfg.attn_every
-            z = jnp.zeros((groups, batch, eff_len, cfg.n_kv_heads, cfg.head_dim), dtype)
-            shared = KVCache(z, z)
+            shared = _shared_kv_zeros(cfg, batch, cache_len, dtype)
     else:
         z = jnp.zeros((L, batch, eff_len, cfg.n_kv_heads, cfg.head_dim), dtype)
         kv = KVCache(z, z)

@@ -317,3 +317,132 @@ def test_block_scopes_leave_compiled_ops_unchanged(program, monkeypatch):
     bare = compiled()
     assert "op_name" not in scoped
     assert scoped == bare
+
+
+# --------------------------------------------------------------------------
+# hybrid stack: one scan over the stacked layers against a layer loop
+# --------------------------------------------------------------------------
+
+def _hybrid_layer_loop(params, cfg, x, positions, mode, *, ssm=None,
+                       shared_kv=None, cache_index=None, cache_len=None):
+    """The oracle: each Mamba2 layer in turn, the shared attention+MLP block
+    after every ``attn_every``-th, each application with its own KV entry.
+    Returns (x, stacked new SSM state, stacked new shared KV)."""
+    from repro.models.layers import apply_attn_block
+    from repro.models.modules import rms_norm
+    from repro.models.ssm import mamba2_forward
+    take = lambda tree, i: (None if tree is None
+                            else jax.tree.map(lambda a: a[i], tree))
+    new_ssm, new_kv = [], []
+    for i in range(cfg.num_layers):
+        bp = take(params["blocks"], i)
+        hin = rms_norm(x, bp["ln"], cfg.norm_eps)
+        if mode == "train":
+            out = mamba2_forward(bp["ssm"], hin, cfg)
+        else:
+            out, st = mamba2_forward(bp["ssm"], hin, cfg, state=take(ssm, i),
+                                     return_state=True)
+            new_ssm.append(st)
+        x = x + out
+        if i % cfg.attn_every == cfg.attn_every - 1:
+            x, kv, _, _ = apply_attn_block(
+                params["shared_attn"], cfg, PCFG, x, positions=positions,
+                mode=mode, cache=take(shared_kv, i // cfg.attn_every),
+                cache_index=cache_index, cache_len=cache_len)
+            new_kv.append(kv)
+    stack = lambda xs: jax.tree.map(lambda *a: jnp.stack(a), *xs) if xs else None
+    return x, stack(new_ssm), stack(new_kv)
+
+
+def _oracle_logits(params, cfg, x):
+    from repro.models.modules import rms_norm
+    return rms_norm(x, params["final_norm"], cfg.norm_eps) @ params["lm_head"]
+
+
+def _hybrid_serve_oracle(params, cfg, toks, S0, cache_len):
+    """Prompt toks[:, :S0], then one decode step a further token: the
+    logits of every step and the last state, by the layer loop."""
+    x = jnp.take(params["embed"], toks[:, :S0], axis=0)
+    pos = jnp.broadcast_to(jnp.arange(S0)[None], toks[:, :S0].shape)
+    x, ssm, kv = _hybrid_layer_loop(params, cfg, x, pos, "prefill",
+                                    cache_len=cache_len)
+    outs = [_oracle_logits(params, cfg, x[:, -1])]
+    for t in range(S0, toks.shape[1]):
+        x = jnp.take(params["embed"], toks[:, t:t + 1], axis=0)
+        pos = jnp.full((toks.shape[0], 1), t, jnp.int32)
+        x, ssm, kv = _hybrid_layer_loop(
+            params, cfg, x, pos, "decode", ssm=ssm, shared_kv=kv,
+            cache_index=jnp.int32(t))
+        outs.append(_oracle_logits(params, cfg, x[:, 0]))
+    return outs, ssm, kv
+
+
+def _hybrid_serve(params, cfg, pcfg, toks, S0, cache_len):
+    logits, state = tfm.prefill(params, {"tokens": toks[:, :S0]}, cfg, pcfg,
+                                cache_len)
+    outs = [logits]
+    for t in range(S0, toks.shape[1]):
+        logits, state = tfm.decode_step(params, toks[:, t:t + 1], state, cfg,
+                                        pcfg)
+        outs.append(logits)
+    return outs, state.ssm, state.shared_kv
+
+
+def _lowered_lines(cfg, groups):
+    """Instruction count of the lowered hybrid decode step with ``groups``
+    applications of the shared block."""
+    cfg = dataclasses.replace(cfg, num_layers=groups * cfg.attn_every)
+    params = jax.eval_shape(lambda: split(tfm.init(KEY, cfg))[0])
+    state = jax.eval_shape(lambda: tfm.init_decode_state(cfg, 2, 32))
+    tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    text = jax.jit(lambda p, t, s: tfm.decode_step(p, t, s, cfg, PCFG)).lower(
+        params, tok, state).as_text()
+    return sum(" = " in line for line in text.splitlines())
+
+
+@pytest.mark.parametrize("case", ["serve", "loss_grad", "unrolled",
+                                  "size_by_groups"])
+def test_hybrid_scan_matches_layer_loop(case):
+    """zamba2's stack runs as one scan over its stacked Mamba2 layers with
+    the shared block inside it; it computes what a plain layer-by-layer
+    loop computes: prompt and decode logits, SSM state and shared KV
+    (``serve``, and unrolled with ``scan_layers=False``), the loss and its
+    gradients (``loss_grad``).  ``size_by_groups``: the lowered decode
+    program does not grow with the number of groups."""
+    cfg = get_config("zamba2-2.7b").reduced()
+    assert cfg.num_layers // cfg.attn_every >= 2
+    if case == "size_by_groups":
+        assert _lowered_lines(cfg, 4) == _lowered_lines(cfg, 2)
+        return
+    params, _ = split(tfm.init(KEY, cfg))
+    toks = jax.random.randint(KEY, (2, 20), 0, cfg.vocab_size)
+    close = dict(atol=1e-5, rtol=1e-5)
+    if case == "loss_grad":
+        batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+
+        def oracle_loss(p):
+            x = jnp.take(p["embed"], toks, axis=0)
+            pos = jnp.broadcast_to(jnp.arange(toks.shape[1])[None], toks.shape)
+            x, _, _ = _hybrid_layer_loop(p, cfg, x, pos, "train")
+            from repro.models.modules import softmax_cross_entropy
+            return softmax_cross_entropy(_oracle_logits(p, cfg, x),
+                                         batch["labels"], cfg.vocab_size)[0]
+
+        for remat in ("none", "block"):
+            pcfg = ParallelConfig(remat=remat)
+            loss, grads = jax.jit(jax.value_and_grad(
+                lambda p: tfm.loss_fn(p, batch, cfg, pcfg)[0]))(params)
+            ref, ref_grads = jax.jit(jax.value_and_grad(oracle_loss))(params)
+            np.testing.assert_allclose(float(loss), float(ref), **close)
+            for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+                np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                           atol=1e-5, rtol=1e-4)
+        return
+    pcfg = ParallelConfig(remat="none", scan_layers=case != "unrolled")
+    got = jax.jit(_hybrid_serve, static_argnums=(1, 2, 4, 5))(
+        params, cfg, pcfg, toks, 16, 32)
+    ref = jax.jit(_hybrid_serve_oracle, static_argnums=(1, 3, 4))(
+        params, cfg, toks, 16, 32)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), **close)

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the Pallas kernels for a described TPU v5e.
+"""Ahead-of-time compiles for a described TPU v5e: the Pallas kernels, and
+zamba2-2.7b's decode and prefill programs.
 
 The TPU compiler is installed with jaxlib and compiles for a chip that is
 described, not attached, so these tests need no accelerator: they catch
@@ -13,16 +14,21 @@ import this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.registry import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.quant8 import dequantize, quantize
 from repro.kernels.reduce_tree import tree_reduce
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models import transformer as tfm
+from repro.models.config import ParallelConfig
+from repro.models.modules import split
 
 
 @pytest.fixture(scope="module")
@@ -92,3 +98,46 @@ def test_tree_reduce_compiles(one_chip):
     text = _compile_text(lambda x: tree_reduce(x), one_chip,
                          ((16, 1 << 20), jnp.bfloat16))
     assert "tpu_custom_call" in text
+
+
+# zamba2-2.7b's stacked in_proj, a per-group slice of it, and its decode
+# state: (layers, d_model, in_proj width), (batch 8) SSM state
+STACKED = "bf16[54,2560,10448]"
+GROUP_SLICE = re.compile(r"bf16\[(1,)?6,2560,10448\]")
+STATE = "f32[54,8,80,64,64]"
+RESULT = re.compile(r"= (\S+) ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_stack_compiles_without_stacked_copies(one_chip, program):
+    """zamba2's 54 stacked Mamba2 layers run as one scan whose operands are
+    the stacked weights and state whole: at real widths (decode: batch 8,
+    cache 1024; prefill: 1 x 4096) no op makes a copy of the stacked
+    in_proj or of the SSM state, nor a per-group slice, and the program
+    needs under 2 GB of temporaries beside its arguments."""
+    cfg = get_config("zamba2-2.7b")
+    pcfg = ParallelConfig()
+    sds = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = sds(jax.eval_shape(lambda: split(tfm.init(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))[0]))
+    if program == "decode":
+        state = sds(jax.eval_shape(lambda: tfm.init_decode_state(cfg, 8, 1024)))
+        tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+        fn = lambda p, t, s: tfm.decode_step(p, t, s, cfg, pcfg)
+        args = (params, tok, state)
+    else:
+        tok = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+        fn = lambda p, t: tfm.prefill(p, {"tokens": t}, cfg, pcfg, 4096)
+        args = (params, tok)
+    compiled = jax.jit(fn).lower(*args).compile()
+    made = RESULT.findall(compiled.as_text())
+    assert any(shape.startswith(STACKED) for shape, _ in made)
+    for shape, op in made:
+        assert not GROUP_SLICE.match(shape), (shape, op)
+        if shape.startswith(STACKED):
+            assert op in ("parameter", "get-tuple-element"), (shape, op)
+        if shape.startswith(STATE):
+            assert op != "copy", (shape, op)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
