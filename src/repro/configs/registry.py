@@ -1,4 +1,4 @@
-"""Architecture registry — the 10 assigned configs + the paper's workloads.
+"""Architecture registry — the assigned configs + the paper's workloads.
 
 Each ``src/repro/configs/<id>.py`` defines ``CONFIG`` with the exact figures
 from the assignment; this registry imports them and offers lookup by id for
@@ -27,6 +27,7 @@ ARCH_IDS = (
     "arctic-480b",
     "mixtral-8x7b",
     "mamba2-1.3b",
+    "zamba2-7b",
 )
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

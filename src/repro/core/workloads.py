@@ -254,6 +254,18 @@ def _layer_param_counts(cfg: "ModelConfig") -> Tuple[float, float]:
         shared = attn + ffn_gated if cfg.d_ff else attn
         resident = active = (_ssm_block_params(cfg) +
                              shared / max(cfg.num_layers, 1))
+    elif cfg.family == "zamba2":
+        # Mamba2 stack + n_shared_blocks shared blocks (q, k, v read 2·d),
+        # each *application* with its own MLP adapter and d×d linear;
+        # resident counts each block once, active once an application
+        d2 = 2 * d
+        block = (d2 * cfg.d_qkv + 2 * d2 * cfg.d_kv + cfg.d_qkv * d
+                 + ffn_gated)
+        own = d * cfg.adapter_rank + cfg.adapter_rank * 2 * cfg.d_ff + d * d
+        n_app, L = cfg.n_applications, max(cfg.num_layers, 1)
+        resident = (_ssm_block_params(cfg) +
+                    (cfg.n_shared_blocks * block + n_app * own) / L)
+        active = _ssm_block_params(cfg) + n_app * (block + own) / L
     elif cfg.family == "audio":
         # encoder: self-attn + 2-matrix GELU MLP; decoder adds cross-attn.
         # Averaged over (enc + dec) layers — Workload.n_layers is the sum.
@@ -310,6 +322,8 @@ def from_model_config(cfg: "ModelConfig", shape: "ShapeConfig",
     quad = 2 * seq_eff * cfg.d_qkv if cfg.n_heads else 0.0
     if cfg.family == "hybrid":
         quad = quad / max(cfg.attn_every, 1)     # shared block cadence
+    elif cfg.family == "zamba2":
+        quad = quad * cfg.n_applications / max(cfg.num_layers, 1)
     flops_fwd = 2 * active + quad
     total_samples = shape.global_batch * shape.seq_len
     samples_per_dp = max(1, total_samples // strategy.dp)

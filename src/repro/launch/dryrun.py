@@ -143,25 +143,42 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     return rec
 
 
+def probe_configs(cfg) -> dict:
+    """The cut copies of ``cfg`` the probe compiles, by name.  "L1" and
+    "L2" hold one and two layers (hybrid: periods of ``attn_every``
+    layers, each ending in the shared block).  zamba2's applications fall
+    at irregular layers: its "L1" and "L2" hold two and three layers with
+    one application, at layer 1, and "L2A" adds a second at layer 2, so
+    L2 − L1 is a layer and L2A − L2 an application.  (An application at
+    layer 0 reads the embedding as its hidden state, and costs less in
+    the backward pass than one at any published layer.)"""
+    def cut(L, **kw):
+        return dataclasses.replace(
+            cfg, num_layers=L, n_enc_layers=min(cfg.n_enc_layers, L), **kw)
+    if cfg.family == "hybrid":
+        return {f"L{L}": cut(cfg.attn_every * L) for L in (1, 2)}
+    if cfg.family == "zamba2":
+        return {"L1": cut(2, hybrid_layer_ids=(1,)),
+                "L2": cut(3, hybrid_layer_ids=(1,)),
+                "L2A": cut(3, hybrid_layer_ids=(1, 2))}
+    return {f"L{L}": cut(L) for L in (1, 2)}
+
+
 def probe_layer_cost(cfg, shape, mesh, pcfg) -> dict:
-    """Compile the step on an L=1 copy and an L=2 copy of the arch with the
+    """Compile the step on the cut copies of ``probe_configs`` with the
     same shardings; per-layer cost = cost(L2) − cost(L1), base = L1 − layer.
     This sidesteps cost_analysis's count-scan-body-once behaviour exactly."""
-    import jax
     from repro.parallel.steps import make_setup
     from repro.launch.roofline import collect_cost, collective_bytes_from_hlo
 
     out = {}
-    for L in (1, 2):
-        c = dataclasses.replace(
-            cfg, num_layers=L if cfg.family != "hybrid" else cfg.attn_every * L,
-            n_enc_layers=min(cfg.n_enc_layers, L))
+    for name, c in probe_configs(cfg).items():
         setup = make_setup(c, shape, mesh, pcfg.replace(scan_layers=False))
         with mesh:
             compiled = setup.step_fn.lower(*setup.example_args).compile()
         cost = collect_cost(compiled)
         colls = collective_bytes_from_hlo(compiled.as_text())
-        out[f"L{L}"] = {"cost": cost, "collective_bytes": colls["total_bytes"]}
+        out[name] = {"cost": cost, "collective_bytes": colls["total_bytes"]}
     return out
 
 
@@ -172,15 +189,21 @@ def corrected_totals(rec, cfg) -> dict:
         return {}
     L = cfg.num_layers
     eff_layers = L // cfg.attn_every if cfg.family == "hybrid" else L
-    l1, l2 = p["L1"], p["L2"]
-    out = {}
-    for key in ("flops", "bytes accessed"):
-        per_layer = max(l2["cost"].get(key, 0) - l1["cost"].get(key, 0), 0)
-        base = max(l1["cost"].get(key, 0) - per_layer, 0)
-        out[key.replace(" ", "_")] = base + per_layer * eff_layers
-    per_layer_coll = max(l2["collective_bytes"] - l1["collective_bytes"], 0)
-    base_coll = max(l1["collective_bytes"] - per_layer_coll, 0)
-    out["collective_bytes"] = base_coll + per_layer_coll * eff_layers
+
+    def total(get):
+        # [(cost of one unit, units in the probe "L1", units in the model)]
+        if "L2A" in p:          # zamba2: a layer and an application apart
+            units = [(get(p["L2"]) - get(p["L1"]), 2, L),
+                     (get(p["L2A"]) - get(p["L2"]), 1, cfg.n_applications)]
+        else:
+            units = [(get(p["L2"]) - get(p["L1"]), 1, eff_layers)]
+        units = [(max(u, 0), n1, n) for u, n1, n in units]
+        base = max(get(p["L1"]) - sum(u * n1 for u, n1, _ in units), 0)
+        return base + sum(u * n for u, _, n in units)
+
+    out = {key.replace(" ", "_"): total(lambda r: r["cost"].get(key, 0))
+           for key in ("flops", "bytes accessed")}
+    out["collective_bytes"] = total(lambda r: r["collective_bytes"])
     return out
 
 

@@ -197,7 +197,7 @@ def param_counts(cfg) -> Tuple[int, int]:
         return 3 * d * ff
 
     total = active = 2 * V * d if not cfg.tie_embeddings else V * d
-    if cfg.family in ("ssm", "hybrid"):
+    if cfg.family in ("ssm", "hybrid", "zamba2"):
         di = cfg.d_inner
         per = d * (2 * di + 2 * cfg.ssm_groups * cfg.ssm_state + cfg.ssm_heads) \
             + di * d + 4 * (di + 2 * cfg.ssm_groups * cfg.ssm_state)
@@ -208,6 +208,14 @@ def param_counts(cfg) -> Tuple[int, int]:
             uses = L // cfg.attn_every
             total += shared
             active += shared * uses   # applied `uses` times per token
+        if cfg.family == "zamba2":
+            # q, k, v read 2·d; each application owns its adapter and L_j
+            hd = cfg.head_dim
+            shared = (2 * d * (cfg.n_heads + 2 * cfg.n_kv_heads) * hd
+                      + cfg.n_heads * hd * d + mlp_params(f))
+            own = d * cfg.adapter_rank + cfg.adapter_rank * 2 * f + d * d
+            total += cfg.n_shared_blocks * shared + cfg.n_applications * own
+            active += cfg.n_applications * (shared + own)
     elif cfg.n_experts:
         per_expert = mlp_params(f)
         per_layer = attn_params() + cfg.n_experts * per_expert + d * cfg.n_experts

@@ -27,7 +27,13 @@ class ModelConfig:
       * ``dense``  — standard decoder-only transformer (llama/qwen/chatglm).
       * ``moe``    — mixture-of-experts FFN (mixtral/arctic).
       * ``ssm``    — attention-free Mamba2 / SSD stack.
-      * ``hybrid`` — Mamba2 blocks + a *shared* attention block (zamba2).
+      * ``hybrid`` — Mamba2 blocks + a *shared* attention block (zamba2,
+                     as the registry's zamba2-2.7b builds it).
+      * ``zamba2`` — Zyphra's published Zamba2 block: ``n_shared_blocks``
+                     shared attention+MLP blocks taken in turn at
+                     ``hybrid_layer_ids``, attending over
+                     concat(hidden, embedding), with a per-application
+                     MLP adapter and linear into the Mamba2 layer's input.
       * ``vlm``    — decoder LM consuming precomputed patch embeddings
                      (llava; frontend is a stub per the task spec).
       * ``audio``  — encoder/decoder transformer consuming precomputed
@@ -35,7 +41,7 @@ class ModelConfig:
     """
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | vlm | audio
+    family: str  # dense | moe | ssm | hybrid | zamba2 | vlm | audio
 
     num_layers: int
     d_model: int
@@ -69,6 +75,11 @@ class ModelConfig:
     ssm_groups: int = 1              # B/C projection groups
     attn_every: int = 0              # hybrid: shared attn block period
 
+    # --- zamba2 (published Zamba2 block) ---------------------------------------
+    n_shared_blocks: int = 0         # num_mem_blocks, applied in turn
+    hybrid_layer_ids: Tuple[int, ...] = ()   # layers a shared block precedes
+    adapter_rank: int = 0            # per-application MLP LoRA rank
+
     # --- encoder/decoder (audio) ----------------------------------------------
     n_enc_layers: int = 0
     enc_seq: int = 0                 # precomputed frame count (whisper: 1500)
@@ -83,6 +94,11 @@ class ModelConfig:
 
     # --- attention applicability metadata -------------------------------------
     subquadratic: bool = False       # may run long_500k decode
+
+    def __post_init__(self):
+        # a configuration file gives the ids as a JSON list
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
 
     # -------------------------------------------------------------------------
     @property
@@ -106,6 +122,11 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
+
+    @property
+    def n_applications(self) -> int:
+        """zamba2: how many times a shared block runs in one pass."""
+        return len(self.hybrid_layer_ids)
 
     @property
     def is_attention_free(self) -> bool:
@@ -132,6 +153,10 @@ class ModelConfig:
             sliding_window=min(self.sliding_window, 16) if self.sliding_window else 0,
             vocab_pad_to=32,
         )
+        if self.family == "zamba2":
+            # two applications of block A around one of B, rank-8 adapters
+            small.update(num_layers=6, hybrid_layer_ids=(1, 3, 5),
+                         adapter_rank=8)
         small.update(overrides)
         return dataclasses.replace(self, **small)
 

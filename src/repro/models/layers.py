@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from .attention import (apply_rope, chunked_attention, decode_attention,
                         dense_attention)
-from .modules import dense_init, ones_init, rms_norm, swiglu, zeros_init
+from .modules import (dense_init, gelu, ones_init, rms_norm, swiglu,
+                      zeros_init)
 from .moe import init_moe, moe_ffn
 
 
@@ -36,20 +37,23 @@ class KVCache(NamedTuple):
 # attention
 # --------------------------------------------------------------------------
 
-def init_attention(key, cfg, dtype=jnp.float32, cross: bool = False):
+def init_attention(key, cfg, dtype=jnp.float32, cross: bool = False,
+                   d_in: Optional[int] = None):
     """QKV/O projections in *flattened* (d, H·hd) layout.
 
     H·hd is divisible by the 16-way TP degree for every assigned arch even
     when H itself is not (llava 56H, qwen1.5 20H, arctic 56H) — jit input
     shardings require exact divisibility; the per-head structure only
     appears on activations, where uneven GSPMD sharding is permitted.
+    ``d_in`` is the width q, k and v read (zamba2: 2·d); the output is d.
     """
     d, Hq, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    di = d_in or d
     ks = jax.random.split(key, 8)
     p = {
-        "wq": dense_init(ks[0], (d, Hq * hd), ("embed", "qkv"), dtype=dtype),
-        "wk": dense_init(ks[1], (d, Hkv * hd), ("embed", "kv"), dtype=dtype),
-        "wv": dense_init(ks[2], (d, Hkv * hd), ("embed", "kv"), dtype=dtype),
+        "wq": dense_init(ks[0], (di, Hq * hd), ("embed", "qkv"), dtype=dtype),
+        "wk": dense_init(ks[1], (di, Hkv * hd), ("embed", "kv"), dtype=dtype),
+        "wv": dense_init(ks[2], (di, Hkv * hd), ("embed", "kv"), dtype=dtype),
         "wo": dense_init(ks[3], (Hq * hd, d), ("qkv", "embed"),
                          scale=1.0 / (d ** 0.5 * (2 * max(cfg.num_layers, 1)) ** 0.5),
                          dtype=dtype),
@@ -92,12 +96,14 @@ def apply_attention(p, cfg, pcfg, x, *, positions, mode: str = "train",
                     cache_len: Optional[int] = None, kv_x=None,
                     causal: bool = True, window: int = 0,
                     constrain=lambda t, kind="residual": t, cache_slot=None,
+                    scale: Optional[float] = None,
                     ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     """Unified attention. Returns (out, new_cache).
 
     ``cache_slot`` (a possibly traced index) names this call's entry in a
     cache stacked on a leading axis: decode writes the new K/V into that
-    entry in place and attends over it, prefill writes its cache there."""
+    entry in place and attends over it, prefill writes its cache there.
+    ``scale`` is the softmax scale (None: head_dim ** -0.5)."""
     with jax.named_scope("attention"):
         B, S, d = x.shape
         cross = kv_x is not None
@@ -133,18 +139,21 @@ def apply_attention(p, cfg, pcfg, x, *, positions, mode: str = "train",
                                                        keepdims=False)
                           for a in new_cache)
             valid = jnp.minimum(cache_index + 1, S_cache)
-            out = decode_attention(q, kc, vc, jnp.broadcast_to(valid, (B,)))
+            out = decode_attention(q, kc, vc, jnp.broadcast_to(valid, (B,)),
+                                   scale=scale)
         else:
             if cross:
                 out = chunked_attention(q, k, v, causal=False,
                                         q_chunk=pcfg.attn_q_chunk,
                                         k_chunk=pcfg.attn_k_chunk)
             elif S <= 512:
-                out = dense_attention(q, k, v, causal=causal, window=window)
+                out = dense_attention(q, k, v, causal=causal, window=window,
+                                      scale=scale)
             else:
                 out = chunked_attention(q, k, v, causal=causal, window=window,
                                         q_chunk=pcfg.attn_q_chunk,
-                                        k_chunk=pcfg.attn_k_chunk)
+                                        k_chunk=pcfg.attn_k_chunk,
+                                        scale=scale)
             if mode == "prefill":
                 new_cache = _build_cache(k, v,
                                          cache_len=cache_len or k.shape[1],
@@ -206,9 +215,32 @@ def init_mlp(key, cfg, dtype=jnp.float32):
     }
 
 
-def apply_mlp(p, x):
+def init_mlp_adapter(key, cfg, dtype=jnp.float32):
+    """zamba2: one application's rank-r adapter of the gate and up
+    projections, ``in_proj`` (d, r) and ``out_proj`` (2, r, d_ff): its
+    gate half and its up half, each sharded as ``w_gate`` and ``w_up``."""
+    d, r, f = cfg.d_model, cfg.adapter_rank, cfg.d_ff
+    ks = jax.random.split(key, 2)
+    return {
+        "in_proj": dense_init(ks[0], (d, r), ("embed", "null"), dtype=dtype),
+        "out_proj": dense_init(ks[1], (2, r, f), ("null", "null", "mlp"),
+                               scale=1.0 / r ** 0.5, dtype=dtype),
+    }
+
+
+def apply_mlp(p, x, adapter=None, act: str = "silu"):
+    """Gated MLP, ``act`` on the gate (silu: SwiGLU; gelu: zamba2's).
+    ``adapter`` (zamba2) adds its low-rank term to the gate and up
+    projections: [g, u] = x·W_gu + (x·A)·B."""
     with jax.named_scope("mlp"):
-        return swiglu(x @ p["w_gate"], x @ p["w_up"]) @ p["w_down"]
+        gate, up = x @ p["w_gate"], x @ p["w_up"]
+        if adapter is not None:
+            with jax.named_scope("adapter"):
+                low = x @ adapter["in_proj"]
+                gate = gate + low @ adapter["out_proj"][0]
+                up = up + low @ adapter["out_proj"][1]
+        gated = (gelu(gate) * up if act == "gelu" else swiglu(gate, up))
+        return gated @ p["w_down"]
 
 
 # --------------------------------------------------------------------------
@@ -270,3 +302,42 @@ def apply_attn_block(p, cfg, pcfg, x, *, positions, mode="train",
         ff = apply_mlp(p["ffn"], y)
     x = constrain(x + ff)
     return x, new_cache, new_cross, aux
+
+
+# --------------------------------------------------------------------------
+# zamba2's shared block (Zyphra's published layer)
+# --------------------------------------------------------------------------
+
+def init_zamba2_block(key, cfg, dtype=jnp.float32):
+    """One of zamba2's shared blocks: a norm over concat(hidden,
+    embedding), attention from that 2·d input, a norm, the gated MLP."""
+    d = cfg.d_model
+    ks = jax.random.split(key, 2)
+    return {
+        "ln1": ones_init((2 * d,), ("embed",), dtype),
+        "attn": init_attention(ks[0], cfg, dtype, d_in=2 * d),
+        "ln2": ones_init((d,), ("embed",), dtype),
+        "ffn": init_mlp(ks[1], cfg, dtype),
+    }
+
+
+def apply_zamba2_block(p, adapter, cfg, pcfg, h, x0, *, positions,
+                       mode="train", cache: Optional[KVCache] = None,
+                       cache_index=None, cache_len: Optional[int] = None,
+                       constrain=lambda t, kind="residual": t,
+                       cache_slot=None):
+    """T = MLP(RMSNorm(Attn(RMSNorm(concat(h, x0))))), no residual inside:
+    the caller takes T into the next Mamba2 layer's input.  ``x0`` is the
+    embedding output; ``adapter`` is this application's, and the MLP's
+    gate is GeLU, as in Zyphra's model.  The softmax
+    scale is (head_dim / 2) ** -0.5, as Zyphra's model has it (its heads
+    are twice as wide as d / n_heads).  Returns (T, new_cache)."""
+    with jax.named_scope("shared_in"):
+        y = rms_norm(jnp.concatenate([h, x0], axis=-1), p["ln1"],
+                     cfg.norm_eps)
+    a, new_cache = apply_attention(
+        p["attn"], cfg, pcfg, y, positions=positions, mode=mode, cache=cache,
+        cache_index=cache_index, cache_len=cache_len, constrain=constrain,
+        cache_slot=cache_slot, scale=(cfg.head_dim / 2) ** -0.5)
+    y = rms_norm(a, p["ln2"], cfg.norm_eps)
+    return apply_mlp(p["ffn"], y, adapter=adapter, act="gelu"), new_cache

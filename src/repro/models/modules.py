@@ -127,7 +127,9 @@ def swiglu(gate, up):
 
 
 def gelu(x):
-    return jax.nn.gelu(x.astype(jnp.float32)).astype(x.dtype)
+    """Exact (erf) GeLU, as PyTorch's default and Zamba2's ``gelu``."""
+    return jax.nn.gelu(x.astype(jnp.float32),
+                       approximate=False).astype(x.dtype)
 
 
 def linear(x, w, b=None):

@@ -244,9 +244,16 @@ def mamba2_forward(params, u, cfg, *, chunk: int = 128,
 
         y = y + x * params["d_skip"].astype(u.dtype)[None, None, :, None]
         y = y.reshape(B, S, di)
-        # gated RMSNorm (mamba2's norm-before-out)
-        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                     params["norm_g"], cfg.norm_eps)
+        # gated RMSNorm (mamba2's norm-before-out), over each group's
+        # d_inner / G channels
+        if G == 1:
+            y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                         params["norm_g"], cfg.norm_eps)
+        else:
+            y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+            y = rms_norm(y.reshape(B, S, G, di // G),
+                         params["norm_g"].reshape(G, di // G),
+                         cfg.norm_eps).reshape(B, S, di)
         out = y @ params["out_proj"]
         if return_state:
             return out, SSMState(h=hT, conv=new_lag)

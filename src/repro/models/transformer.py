@@ -1,4 +1,5 @@
-"""Decoder-only LM covering the dense / moe / ssm / hybrid / vlm families.
+"""Decoder-only LM covering the dense / moe / ssm / hybrid / zamba2 / vlm
+families.
 
 One model class (functions + pytrees, no framework) serves all ten assigned
 architectures.  Layers are *stacked* on a leading ``layers`` axis and
@@ -12,6 +13,15 @@ weights, applied num_layers/attn_every times, each application with its own
 KV cache slice — weights shared, activations not).  It runs as the ssm
 family does, one scan over the stacked Mamba2 layers, with the shared block
 inside the scan body under a ``lax.cond`` on the layer index.
+
+zamba2 (Zyphra's published block) runs in the same scan: before each
+layer of ``hybrid_layer_ids`` the scan body takes application j's shared
+block, ``j % n_shared_blocks``, by a ``lax.switch`` over the blocks sliced
+statically outside the scan; it reads concat(h, embedding), and its
+output T enters that Mamba2 layer's input only:
+h ← h + Mamba2(RMSNorm(h + L_j·T)).  The small per-application leaves
+(the MLP adapter, L_j) are indexed by j.  Each application keeps its own
+entry of the shared KV cache.
 
 Each block kind runs under one ``jax.named_scope`` (``embed``, ``ssm`` with
 ``ssd`` inside it, ``attention``, ``mlp``, ``moe``, ``lm_head``, ``loss``;
@@ -38,7 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig, ParallelConfig
-from .layers import (KVCache, apply_attn_block, init_attn_block)
+from .layers import (KVCache, apply_attn_block, apply_zamba2_block,
+                     init_attn_block, init_mlp_adapter, init_zamba2_block)
 from .modules import (Box, AxisNames, dense_init, embed_init, ones_init,
                       rms_norm, softmax_cross_entropy, split)
 from .ssm import SSMState, init_mamba2, init_ssm_state, mamba2_forward
@@ -48,7 +59,7 @@ class DecodeState(NamedTuple):
     """Everything carried between decode steps (pytree)."""
     kv: Any            # stacked KVCache or None
     ssm: Any           # stacked SSMState or None
-    shared_kv: Any     # hybrid: (groups,) stacked KVCache for the shared block
+    shared_kv: Any     # hybrid, zamba2: stacked KVCache, one per application
     cross_kv: Any      # enc-dec: stacked static cross-attention cache
     index: jnp.ndarray  # scalar int32 — next write position / #tokens seen
 
@@ -86,21 +97,28 @@ def init(key, cfg: ModelConfig, dtype=jnp.float32):
 
     lkeys = jax.random.split(keys[2], max(cfg.num_layers, 1))
     ffn = "moe" if cfg.n_experts else "mlp"
-    if cfg.family == "ssm":
+    if cfg.family in ("ssm", "hybrid", "zamba2"):
         params["blocks"] = _stack_init(
             lambda k: {"ln": ones_init((cfg.d_model,), ("embed",), dtype),
                        "ssm": init_mamba2(k, cfg, dtype)}, lkeys)
-    elif cfg.family == "hybrid":
-        params["blocks"] = _stack_init(
-            lambda k: {"ln": ones_init((cfg.d_model,), ("embed",), dtype),
-                       "ssm": init_mamba2(k, cfg, dtype)}, lkeys)
-        params["shared_attn"] = init_attn_block(keys[3], cfg, dtype)
     else:
         with_cross = cfg.family == "audio"
         params["blocks"] = _stack_init(
             lambda k: init_attn_block(k, cfg, dtype, ffn=ffn,
                                       with_cross=with_cross), lkeys)
-
+    if cfg.family == "hybrid":
+        params["shared_attn"] = init_attn_block(keys[3], cfg, dtype)
+    if cfg.family == "zamba2":
+        params["shared_blocks"] = _stack_init(
+            lambda k: init_zamba2_block(k, cfg, dtype),
+            jax.random.split(keys[3], cfg.n_shared_blocks))
+        akeys = jax.random.split(keys[6], cfg.n_applications)
+        params["adapter"] = _stack_init(
+            lambda k: init_mlp_adapter(k, cfg, dtype), akeys)
+        params["shared_out"] = _stack_init(
+            lambda k: {"out_proj": dense_init(
+                k, (cfg.d_model, cfg.d_model), ("embed", "embed_out"),
+                dtype=dtype)}, jax.random.split(keys[7], cfg.n_applications))
     if cfg.family == "vlm":
         params["mm_proj"] = dense_init(keys[4], (cfg.d_model, cfg.d_model),
                                        ("embed", "embed_out"), dtype=dtype)
@@ -149,7 +167,7 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
     that carry no cache/state (train) pay zero memory for them.
     """
     L = cfg.num_layers
-    is_ssm_family = cfg.family in ("ssm", "hybrid")
+    is_ssm_family = cfg.family in ("ssm", "hybrid", "zamba2")
 
     def maybe_scan(body, carry, xs, length):
         """lax.scan, or an unrolled python loop when ``scan_layers=False``
@@ -171,6 +189,78 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
         # runs inside it after every ``attn_every``-th layer, so the stacked
         # weights and state are scan operands whole, never sliced by group
         every = cfg.attn_every if cfg.family == "hybrid" else 0
+        skv = None
+        if cfg.family != "ssm" and mode == "decode":
+            skv = shared_kv
+        elif cfg.family != "ssm" and mode == "prefill":
+            # each application writes its prompt's KV into a zero buffer
+            skv = _shared_kv_zeros(cfg, x.shape[0], cache_len or x.shape[1],
+                                   x.dtype)
+        scan_ssm = ssm if mode == "decode" else None
+
+        def mamba_layer(h, u, bp, st):
+            """h + Mamba2(RMSNorm(u)), and the layer's new state (decode
+            and prefill).  The norm reads h itself, or zamba2's h + L_j·T."""
+            u = rms_norm(u, bp["ln"], cfg.norm_eps)
+            if mode == "train":
+                return constrain(h + mamba2_forward(bp["ssm"], u, cfg)), None
+            out, new_st = mamba2_forward(bp["ssm"], u, cfg, state=st,
+                                         return_state=True)
+            return constrain(h + out), new_st
+
+        if cfg.family == "zamba2":
+            x0 = x
+            nb = cfg.n_shared_blocks
+            # each shared block sliced once, outside the scan: the switch
+            # below picks among whole blocks, never a dynamic slice of them
+            shared = [jax.tree.map(lambda a, k=k: a[k],
+                                   params["shared_blocks"])
+                      for k in range(nb)]
+
+            def hybrid_in(h, skv, j, k):
+                """Application j, of block k: the Mamba2 layer's input
+                h + L_j·T, and the cache with its KV written."""
+                pick = lambda t: jax.lax.dynamic_index_in_dim(
+                    t, j, keepdims=False)
+                adapter = jax.tree.map(pick, params["adapter"])
+                t, skv = apply_zamba2_block(
+                    shared[k], adapter, cfg, pcfg, h, x0,
+                    positions=positions, mode=mode, cache=skv,
+                    cache_index=cache_index, cache_len=cache_len,
+                    constrain=constrain, cache_slot=j)
+                with jax.named_scope("shared_in"):
+                    hin = h + t @ pick(params["shared_out"]["out_proj"])
+                return constrain(hin), skv
+
+            branches = [lambda h, skv, j: (h, skv)] + [
+                functools.partial(hybrid_in, k=k) for k in range(nb)]
+
+            def mamba_input(h, skv, sel, j):
+                """Branch ``sel``: 0 a plain layer, 1 + k block k."""
+                if isinstance(sel, (int, np.integer)):   # unrolled
+                    return branches[sel](h, skv, j)
+                return jax.lax.switch(sel, branches, h, skv, j)
+            if pcfg.scan_layers:
+                # as the hybrid's shared block: keep only the inputs
+                mamba_input = jax.checkpoint(
+                    mamba_input,
+                    policy=jax.checkpoint_policies.nothing_saveable)
+
+            def zbody(carry, xs):
+                h, skv = carry
+                bp, st, (sel, j) = xs
+                bp = layer_constrain(bp)
+                hin, skv = mamba_input(h, skv, sel, j)
+                h, new_st = _maybe_remat(mamba_layer, pcfg)(h, hin, bp, st)
+                return (h, skv), new_st
+
+            app = np.full(L, -1, np.int32)
+            app[list(cfg.hybrid_layer_ids)] = np.arange(cfg.n_applications)
+            sel = np.where(app >= 0, 1 + app % nb, 0).astype(np.int32)
+            (x, skv), new_ssm = maybe_scan(
+                zbody, (x, skv),
+                (params["blocks"], scan_ssm, (sel, np.maximum(app, 0))), L)
+            return x, None, new_ssm, skv, None, jnp.zeros((), jnp.float32)
 
         def shared_block(h, skv, g):
             """Application g of the shared attention+MLP block; its KV is
@@ -203,29 +293,13 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
             # re-pin the per-layer slice to its stored sharding so FSDP
             # all-gathers happen inside the loop body, not on the whole stack
             bp = layer_constrain(bp)
-
-            def run(h, bp, st):
-                hin = rms_norm(h, bp["ln"], cfg.norm_eps)
-                if mode == "train":
-                    out = mamba2_forward(bp["ssm"], hin, cfg)
-                    return constrain(h + out), None
-                out, new_st = mamba2_forward(bp["ssm"], hin, cfg,
-                                             state=st, return_state=True)
-                return constrain(h + out), new_st
-            run = _maybe_remat(run, pcfg)
+            run = _maybe_remat(lambda h, bp, st: mamba_layer(h, h, bp, st),
+                               pcfg)
             h, new_st = run(h, bp, st)
             if every:
                 h, skv = shared_after(h, skv, i)
             return (h, skv), new_st
 
-        skv = None
-        if every and mode == "decode":
-            skv = shared_kv
-        elif every and mode == "prefill":
-            # each application writes its prompt's KV into a zero buffer
-            skv = _shared_kv_zeros(cfg, x.shape[0], cache_len or x.shape[1],
-                                   x.dtype)
-        scan_ssm = ssm if mode == "decode" else None
         index = np.arange(L, dtype=np.int32) if every else None
         (x, skv), new_ssm = maybe_scan(body, (x, skv),
                                        (params["blocks"], scan_ssm, index), L)
@@ -308,8 +382,9 @@ def _shared_kv_zeros(cfg: ModelConfig, batch: int, cache_len: int,
                      dtype) -> KVCache:
     """The hybrid's shared-block KV cache, one entry per application."""
     eff_len = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
-    z = jnp.zeros((cfg.num_layers // cfg.attn_every, batch, eff_len,
-                   cfg.n_kv_heads, cfg.head_dim), dtype)
+    apps = (cfg.n_applications if cfg.family == "zamba2"
+            else cfg.num_layers // cfg.attn_every)
+    z = jnp.zeros((apps, batch, eff_len, cfg.n_kv_heads, cfg.head_dim), dtype)
     return KVCache(z, z)
 
 
@@ -319,9 +394,9 @@ def init_decode_state(cfg: ModelConfig, batch: int, cache_len: int,
     L = cfg.num_layers
     kv = ssm = shared = cross = None
     eff_len = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
-    if cfg.family in ("ssm", "hybrid"):
+    if cfg.family in ("ssm", "hybrid", "zamba2"):
         ssm = jax.vmap(lambda _: init_ssm_state(cfg, batch, dtype))(jnp.arange(L))
-        if cfg.family == "hybrid":
+        if cfg.family in ("hybrid", "zamba2"):
             shared = _shared_kv_zeros(cfg, batch, cache_len, dtype)
     else:
         z = jnp.zeros((L, batch, eff_len, cfg.n_kv_heads, cfg.head_dim), dtype)

@@ -18,6 +18,12 @@ Divisibility-aware policy (documented in DESIGN.md §6):
   (ZeRO-3 style; GSPMD inserts the per-layer all-gathers); under ``zero1``
   only optimizer state takes the data sharding; under ``replicated``
   neither does.
+* zamba2's new leaves reuse these names: its shared blocks' q, k and v
+  read a 2·d input named ``embed`` (FSDP over ``data``) and write heads
+  (``qkv``, TP); the MLP adapter's ``in_proj`` is (``embed``, r) and its
+  gate and up halves are ``mlp`` as ``w_gate``/``w_up`` are; L_j is
+  (``embed``, ``embed_out``).  The 112 SSM heads take ``model``, and the
+  per-group gated norm of its two B/C groups then stays on one chip.
 * MoE ``expert`` shards over ``model`` when divisible (arctic 128/16),
   otherwise experts stay replicated and their ``mlp`` hidden dim takes the
   TP sharding (mixtral 8e over 16-way TP).
@@ -245,11 +251,11 @@ class Ruleset:
         mesh = self.mesh
         ns = lambda spec: NamedSharding(mesh, spec)
         kv = ssm = shared = cross = None
-        if cfg.family in ("ssm", "hybrid"):
+        if cfg.family in ("ssm", "hybrid", "zamba2"):
             from repro.models.ssm import SSMState
             hspec, cspec = self.ssm_state_spec(global_batch)
             ssm = SSMState(h=ns(hspec), conv=ns(cspec))
-            if cfg.family == "hybrid":
+            if cfg.family in ("hybrid", "zamba2"):
                 shared = KVCache(ns(self.kv_cache_spec(global_batch)),
                                  ns(self.kv_cache_spec(global_batch)))
         else:

@@ -150,6 +150,17 @@ def test_adapter_total_params_sane():
     assert w.params_total == pytest.approx(480e9, rel=0.2)
 
 
+def test_adapter_counts_zamba2_applications():
+    """zamba2-7b: 81 Mamba2 layers (78.4 M each), two shared blocks held
+    once (334 M each), 13 applications each with its adapter and d x d
+    linear (17.0 M), the tied embedding (115 M): 7.35 B resident.  Each
+    application runs its block again, so the active count exceeds it."""
+    w = from_model_config(_cfg("zamba2-7b"), _shape(), Strategy(1, 1, 1))
+    assert w.params_total == pytest.approx(7.35e9, rel=0.01)
+    active = w.params_total * w.active_param_fraction
+    assert active == pytest.approx(7.35e9 + 11 * 334e6, rel=0.01)
+
+
 def test_adapter_moe_active_fraction():
     w = from_model_config(_cfg("mixtral-8x7b"), _shape(), Strategy(1, 1, 1))
     assert w.active_param_fraction < 0.5          # top-2 of 8 experts

@@ -216,7 +216,7 @@ def test_ssd_state_carry():
 # --------------------------------------------------------------------------
 
 SCOPES = {"embed", "ssm", "ssd", "attention", "mlp", "moe", "lm_head",
-          "loss", "optimizer"}
+          "loss", "optimizer", "adapter", "shared_in"}
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 METADATA = re.compile(r",? metadata=\{[^}]*\}")
 MATMUL = re.compile(r"\s(dot|convolution)\(")
@@ -226,7 +226,8 @@ NAME = re.compile(r"%[\w.-]+")
 def _lowered(program):
     """(jitted program, its arguments) of one scoped program at a tiny
     size: zamba2 prefill and decode, a mixtral prefill (moe), a mamba2
-    train step with block remat (loss, optimizer, the backward pass)."""
+    and a zamba2-7b train step with block remat (loss, optimizer, the
+    backward pass; zamba2-7b's adapter and shared_in)."""
     from repro.train.optim import OptimConfig, adam_update, init_adam
     arch, kind = program.split(":")
     cfg = get_config(arch).reduced()
@@ -269,6 +270,9 @@ SCOPED_PROGRAMS = {
     "mixtral-8x7b:prefill": {"embed", "attention", "moe", "lm_head"},
     "mamba2-1.3b:train": {"embed", "ssm", "ssd", "lm_head", "loss",
                           "optimizer"},
+    "zamba2-7b:train": {"embed", "ssm", "ssd", "attention", "mlp",
+                        "adapter", "shared_in", "lm_head", "loss",
+                        "optimizer"},
 }
 
 
@@ -446,3 +450,149 @@ def test_hybrid_scan_matches_layer_loop(case):
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
         assert g.shape == r.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), **close)
+
+
+# --------------------------------------------------------------------------
+# zamba2: Zyphra's published block against the benchmark's plain reference
+# --------------------------------------------------------------------------
+
+def _zamba2_reference():
+    """``benchmarks/chip/reference/zamba2.py``: plain float32, written from
+    the published equations, importing nothing of the program."""
+    import sys
+    from pathlib import Path
+    bench = str(Path(__file__).resolve().parents[1] / "benchmarks" / "chip")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import zamba2
+    return zamba2
+
+
+def _zamba2_tiny():
+    """12 layers with hybrid ids 3, 7, 11: block A runs at 3 and 11, B at
+    7; rank-4 adapters, 2 B/C groups, d 64, MHA of 4 heads of 16 (the
+    published model's heads are all KV heads too)."""
+    cfg = dataclasses.replace(get_config("zamba2-7b").reduced(),
+                              num_layers=12, hybrid_layer_ids=(3, 7, 11),
+                              adapter_rank=4, n_kv_heads=4)
+    assert cfg.ssm_groups == 2 and cfg.n_shared_blocks == 2
+    m = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+    return cfg, m
+
+
+def _zamba2_logits(params, cfg, toks):
+    x, positions = tfm._embed_inputs(params, cfg, {"tokens": toks},
+                                     lambda t, kind="residual": t)
+    x = tfm._scan_blocks(params, cfg, PCFG, x, positions,
+                         lambda t, kind="residual": t)[0]
+    return tfm._lm_head(params, cfg, x, lambda t, kind="residual": t
+                        )[..., :cfg.vocab_size]
+
+
+# Both sides compute in float32 at HIGHEST; what is left is summation
+# order (SSD chunks of 128 against the reference's 64, blocked against
+# plain softmax), about 1e-6 on logits of about 1 here.  A wrong
+# application index, block or group norm moves them by 1e-2 or more.
+ZAMBA2_TOL = dict(atol=2e-5, rtol=2e-5)
+
+
+def test_zamba2_forward_matches_reference():
+    ref = _zamba2_reference()
+    cfg, m = _zamba2_tiny()
+    params, _ = split(tfm.init(KEY, cfg))
+    toks = jax.random.randint(KEY, (2, 40), 0, cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda p, t: _zamba2_logits(p, cfg, t))(params, toks)
+        want = jax.jit(lambda p, t: ref.forward(p, t, m))(params, toks)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **ZAMBA2_TOL)
+
+
+def test_zamba2_prefill_decode_match_reference():
+    """The prompt through ``prefill``, then each further token through
+    ``decode_step`` and the per-application KV cache, against the
+    reference's full forward at every position."""
+    ref = _zamba2_reference()
+    cfg, m = _zamba2_tiny()
+    params, _ = split(tfm.init(KEY, cfg))
+    S0, S = 16, 24
+    toks = jax.random.randint(KEY, (2, S), 0, cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        logits, state = jax.jit(lambda p, t: tfm.prefill(
+            p, {"tokens": t}, cfg, PCFG, 32))(params, toks[:, :S0])
+        assert state.shared_kv.k.shape == (3, 2, 32, 4, 16)
+        step = jax.jit(lambda p, t, s: tfm.decode_step(p, t, s, cfg, PCFG))
+        outs = [logits]
+        for t in range(S0, S - 1):
+            logits, state = step(params, toks[:, t:t + 1], state)
+            outs.append(logits)
+        want = jax.jit(lambda p, t: ref.forward(
+            p, t, m, positions=np.arange(S0 - 1, S - 1)))(params, toks)
+    got = jnp.stack(outs, axis=1)[..., :cfg.vocab_size]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **ZAMBA2_TOL)
+
+
+@pytest.mark.parametrize("remat", ["none", "block"])
+def test_zamba2_loss_and_grads_match_reference(remat):
+    """The loss and every leaf's gradient, with the scan's checkpointed
+    shared block under both remat settings.  Gradients are compared by
+    each leaf's relative L2 gap: 1e-4 is a hundred times what summation
+    order gives here."""
+    ref = _zamba2_reference()
+    cfg, m = _zamba2_tiny()
+    params, _ = split(tfm.init(KEY, cfg))
+    toks = jax.random.randint(KEY, (2, 33), 0, cfg.vocab_size)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+    pcfg = ParallelConfig(remat=remat)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: tfm.loss_fn(p, batch, cfg, pcfg)[0]))(params)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss(p, batch, m)))(params)
+    # a mean of float32 cross-entropies near ln(256): summation order only
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree.leaves(want_grads)):
+        gap = float(jnp.linalg.norm(g - r) / jnp.linalg.norm(r))
+        assert gap < 1e-4, (jax.tree_util.keystr(path), gap)
+
+
+@pytest.mark.parametrize("change", ["swap_blocks", "zero_adapter"])
+def test_zamba2_application_index_matters(change):
+    """A wrong application index cannot pass the comparisons above:
+    swapping blocks A and B, or zeroing the middle application's adapter
+    (block B's only one), moves the logits far beyond their tolerance."""
+    cfg, _ = _zamba2_tiny()
+    params, _ = split(tfm.init(KEY, cfg))
+    toks = jax.random.randint(KEY, (2, 24), 0, cfg.vocab_size)
+    altered = dict(params)
+    if change == "swap_blocks":
+        altered["shared_blocks"] = jax.tree.map(lambda a: a[::-1],
+                                                params["shared_blocks"])
+    else:
+        altered["adapter"] = jax.tree.map(lambda a: a.at[1].set(0.0),
+                                          params["adapter"])
+    fwd = jax.jit(lambda p: _zamba2_logits(p, cfg, toks))
+    gap = float(jnp.max(jnp.abs(fwd(altered) - fwd(params))))
+    assert gap > 100 * ZAMBA2_TOL["atol"], gap
+
+
+def test_zamba2_engine_serves_reference_greedy_tokens():
+    """``serve.Engine`` (its jitted prefill and decode_step) serves the
+    tokens the reference's full forward ranks first at every step."""
+    from repro.serve.engine import Engine, EngineConfig, Request
+    ref = _zamba2_reference()
+    cfg, m = _zamba2_tiny()
+    params, _ = split(tfm.init(KEY, cfg))
+    eng = Engine(params, cfg, ecfg=EngineConfig(max_batch=2, cache_len=32))
+    prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8, 2, 8, 1, 8]]
+    reqs = [Request(uid=i, prompt=p, max_new_tokens=6)
+            for i, p in enumerate(prompts)]
+    with jax.default_matmul_precision("highest"):
+        eng.run_batch(reqs)
+        for r in reqs:
+            seq = jnp.asarray([r.prompt + r.output[:-1]], jnp.int32)
+            logits = ref.forward(params, seq, m, positions=np.arange(
+                len(r.prompt) - 1, seq.shape[1]))[0]
+            assert r.output == [int(t) for t in jnp.argmax(logits, -1)]
