@@ -339,8 +339,106 @@ def test_engine_spans_leave_tokens_alone(tmp_path):
              for line in p.lines for e in line.events}
     assert {"engine.run_batch", "engine.prefill", "engine.decode",
             "engine.logits_to_host", "engine.sample"} <= names
-    # the two programs by their functions' names, not as lambdas
-    assert {"PjitFunction(prefill)", "PjitFunction(decode_step)"} <= names
+    # the programs by their functions' names, not as lambdas
+    assert {"PjitFunction(prefill)", "PjitFunction(decode_step)",
+            "PjitFunction(_choose)"} <= names
+
+
+# the Engine's token choice on the device (serve.engine.choose_tokens)
+
+def _choose_steps(logits, temperature, top_k, uid, steps, vocab, seed=7):
+    """(steps, B) tokens: the same rows chosen at steps 0 .. steps - 1."""
+    from repro.serve.engine import choose_tokens
+    top_k = np.asarray(top_k, np.int32)
+    args = (jnp.asarray(logits), jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_k), jnp.asarray(uid, jnp.uint32),
+            jax.random.PRNGKey(seed))
+    return np.asarray(jax.jit(jax.vmap(lambda s: choose_tokens(
+        *args, s, vocab=vocab, k=int(top_k.max()))))(jnp.arange(steps)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_choose_greedy_matches_host_argmax(dtype):
+    """Greedy rows are ``np.argmax`` of the float32 row's first ``vocab``
+    columns, bit for bit: a padded column's larger logit is never chosen,
+    and of equal logits the first wins; top_k does not touch them."""
+    V, Vp = 50, 64
+    logits = np.array(jnp.asarray(
+        np.random.default_rng(3).standard_normal((5, Vp)) * 3, dtype))
+    logits[1, V + 4] = 100.0                  # best in a padded column
+    logits[2, [7, 31]] = 20.0                 # a tie for the best
+    logits[3, :V] = 0.0                       # the whole row tied
+    got = _choose_steps(logits, [0.0, 0.0, 0.0, 0.0, -1.0], [0, 5, 50, 0, 3],
+                        [1, 2, 3, 4, 5], 1, V)[0]
+    want = np.asarray(logits, np.float32)[:, :V].argmax(-1)
+    assert got.tolist() == want.tolist()
+    assert got[2] == 7 and got[3] == 0
+
+
+def test_choose_draws_within_top_k_ties_kept():
+    """Every sampled token lies among the row's ``top_k`` best, those tied
+    with the k-th best included; each row keeps its own k."""
+    V = 40
+    row = np.linspace(-4.0, -1.0, V).astype(np.float32)
+    row[[3, 5]] = [3.0, 2.0]
+    row[[10, 20, 30]] = 1.5                   # tied at the 3rd best
+    logits = np.stack([row, row, row[::-1]])
+    steps = _choose_steps(logits, [0.7, 0.7, 1.0], [3, 1, 4], [1, 2, 3],
+                          600, V)
+    assert set(steps[:, 0]) == {3, 5, 10, 20, 30}
+    assert set(steps[:, 1]) == {3}
+    assert set(steps[:, 2]) == {V - 1 - i for i in (3, 5, 10, 20, 30)}
+
+
+def test_choose_top_k_zero_draws_from_whole_vocabulary():
+    """``top_k`` 0 at a temperature above 0 draws beyond any top-k: near
+    uniform logits over 256 tokens put most draws outside the best 8."""
+    V = 256
+    row = (0.1 * np.random.default_rng(5).standard_normal(V)).astype(
+        np.float32)
+    steps = _choose_steps(row[None], [1.0], [0], [9], 200, V)[:, 0]
+    assert not set(steps) <= set(np.argsort(row)[-8:].tolist())
+    assert len(set(steps)) > 50
+
+
+def test_choose_same_draw_in_any_slot():
+    """The same key, step, uid and row give the same token in any slot of
+    any batch, beside any other rows; another step draws anew."""
+    V = 64
+    rng = np.random.default_rng(11)
+    row = rng.standard_normal(V).astype(np.float32)
+    small = np.stack([row, rng.standard_normal(V)]).astype(np.float32)
+    big = rng.standard_normal((5, V)).astype(np.float32)
+    big[3] = row
+    got_small = _choose_steps(small, [0.9, 0.0], [0, 0], [42, 1], 40, V)
+    got_big = _choose_steps(big, [0.5, 0.0, 0.7, 0.9, 1.3],
+                            [4, 0, 8, 0, 0], [7, 8, 9, 42, 10], 40, V)
+    assert got_small[:, 0].tolist() == got_big[:, 3].tolist()
+    assert len(set(got_small[:, 0])) > 5
+
+
+def test_choose_samples_the_tempered_top_k_distribution():
+    """4000 draws from one fixed row (64 logits, 2 x a standard normal)
+    at top-k 8 and T 0.7 lie within 0.05 total variation of
+    softmax(top-8 / 0.7), and more than 0.08 from softmax(top-8 / 1.4): a
+    wrong temperature is seen.  The row keeps the two exact distributions
+    at least 0.15 apart (sampling noise at 4000 draws is under 0.03)."""
+    def tv(p, q):
+        return 0.5 * float(np.abs(p - q).sum())
+
+    def exact(row, t):
+        z = np.where(row >= np.sort(row)[-8], row / t, -np.inf)
+        p = np.exp(z - z.max())
+        return p / p.sum()
+
+    row = (2 * np.random.default_rng(1).standard_normal(64)).astype(
+        np.float32)
+    p07, p14 = exact(row, 0.7), exact(row, 1.4)
+    assert tv(p07, p14) >= 0.15
+    draws = _choose_steps(row[None], [0.7], [8], [5], 4000, 64)[:, 0]
+    freq = np.bincount(draws, minlength=64) / len(draws)
+    assert tv(freq, p07) < 0.05
+    assert tv(freq, p14) > 0.08
 
 
 # --------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Ahead-of-time compiles for a described TPU v5e: the Pallas kernels, and
-zamba2-2.7b's decode and prefill programs.
+"""Ahead-of-time compiles for a described TPU v5e: the Pallas kernels,
+zamba2-2.7b's decode and prefill programs, and the Engine's token choice.
 
 The TPU compiler is installed with jaxlib and compiles for a chip that is
 described, not attached, so these tests need no accelerator: they catch
@@ -29,6 +29,7 @@ from repro.kernels.ssd_scan import ssd_scan
 from repro.models import transformer as tfm
 from repro.models.config import ParallelConfig
 from repro.models.modules import split
+from repro.serve.engine import choose_tokens
 
 
 @pytest.fixture(scope="module")
@@ -141,3 +142,20 @@ def test_hybrid_stack_compiles_without_stacked_copies(one_chip, program):
         if shape.startswith(STATE):
             assert op != "copy", (shape, op)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("batch,k", [(8, 50), (1, 0)])
+def test_choose_tokens_compiles(one_chip, batch, k):
+    """The Engine's token choice at zamba2.gen's (batch 8, top-50) and
+    zamba2.ttft-4k's (batch 1, greedy) shapes over the bf16 logits of a
+    32000-token vocabulary: XLA's exact TopK where there is a top-k, no
+    approximate one and no Pallas kernel, a few MB of temporaries."""
+    compiled = jax.jit(lambda *a: choose_tokens(*a, vocab=32000, k=k)).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((batch, 32000), jnp.bfloat16), ((batch,), jnp.float32),
+            ((batch,), jnp.int32), ((batch,), jnp.uint32),
+            ((2,), jnp.uint32), ((), jnp.int32))]).compile()
+    text = compiled.as_text()
+    assert ('custom_call_target="TopK"' in text) == bool(k)
+    assert "Approx" not in text and "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
