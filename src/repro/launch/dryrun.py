@@ -188,7 +188,6 @@ def corrected_totals(rec, cfg) -> dict:
     if not p:
         return {}
     L = cfg.num_layers
-    eff_layers = L // cfg.attn_every if cfg.family == "hybrid" else L
 
     def total(get):
         # [(cost of one unit, units in the probe "L1", units in the model)]
@@ -196,7 +195,8 @@ def corrected_totals(rec, cfg) -> dict:
             units = [(get(p["L2"]) - get(p["L1"]), 2, L),
                      (get(p["L2A"]) - get(p["L2"]), 1, cfg.n_applications)]
         else:
-            units = [(get(p["L2"]) - get(p["L1"]), 1, eff_layers)]
+            # a hybrid's unit is a period ending in its shared block
+            units = [(get(p["L2"]) - get(p["L1"]), 1, cfg.n_applications or L)]
         units = [(max(u, 0), n1, n) for u, n1, n in units]
         base = max(get(p["L1"]) - sum(u * n1 for u, n1, _ in units), 0)
         return base + sum(u * n for u, _, n in units)
@@ -307,10 +307,7 @@ def serving_compare(arch: str = "llama3.2-1b", *, prompt_tokens: int = 16,
                        vocab_size=vocab_size)
     params, _ = split(tfm.init(jax.random.PRNGKey(0), cfg))
     ecfg = EngineConfig(max_batch=batch,
-                        cache_len=prompt_tokens + output_tokens,
-                        target_p99_ms=objective.target_p99_ms,
-                        arrival_rate_rps=(objective.concurrent_users /
-                                          objective.think_time_s))
+                        cache_len=prompt_tokens + output_tokens)
     engine = Engine(params, cfg, ecfg=ecfg)
     reqs = [Request(uid=i, prompt=list(range(1, prompt_tokens + 1)),
                     max_new_tokens=output_tokens) for i in range(batch)]

@@ -205,9 +205,8 @@ def param_counts(cfg) -> Tuple[int, int]:
         active += per * L
         if cfg.family == "hybrid":
             shared = attn_params() + mlp_params(f)
-            uses = L // cfg.attn_every
             total += shared
-            active += shared * uses   # applied `uses` times per token
+            active += shared * cfg.n_applications   # applied per token
         if cfg.family == "zamba2":
             # q, k, v read 2·d; each application owns its adapter and L_j
             hd = cfg.head_dim

@@ -2,8 +2,8 @@
 memory-bounded chunked ("flash-style") implementation in pure jnp.
 
 The chunked implementation is the *reference semantics* for the Pallas
-flash kernel in ``repro.kernels.flash_attention`` and is what the dry-run
-lowers (Pallas runs only on real TPUs; see ``ParallelConfig.use_pallas``).
+flash kernel in ``repro.kernels.flash_attention``, and what every model
+program runs.
 
 Design notes
 ------------

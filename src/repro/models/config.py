@@ -4,12 +4,9 @@ Every assigned architecture (and the paper's own workloads) is described by a
 :class:`ModelConfig`.  The config is a *complete* architectural description:
 the model code in ``repro.models`` consumes nothing else.
 
-``ParallelConfig`` holds the distribution policy knobs that the runtime
+``ParallelConfig`` holds the distribution policy that the runtime
 (``repro.parallel``) uses to derive parameter/activation shardings for a
-given mesh.  The FRED-inspired collective schedule is selected here as well
-(``grad_sync``), so that the paper-faithful baseline ("flat" endpoint-style
-ring all-reduce) and the FRED-style hierarchical schedule can be compared
-like-for-like on the same model.
+given mesh, and the step's remat and attention chunking.
 """
 
 from __future__ import annotations
@@ -124,9 +121,22 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
 
     @property
+    def application_layers(self) -> Tuple[int, ...]:
+        """The layers at which a shared block runs, one entry per
+        application: zamba2's ``hybrid_layer_ids`` (each application
+        before its layer), the hybrid's every ``attn_every``-th layer (each
+        after its layer), none for every other family."""
+        if self.family == "zamba2":
+            return self.hybrid_layer_ids
+        if self.family == "hybrid":
+            return tuple(range(self.attn_every - 1, self.num_layers,
+                               self.attn_every))
+        return ()
+
+    @property
     def n_applications(self) -> int:
-        """zamba2: how many times a shared block runs in one pass."""
-        return len(self.hybrid_layer_ids)
+        """How many times a shared block runs in one pass."""
+        return len(self.application_layers)
 
     @property
     def is_attention_free(self) -> bool:
@@ -248,40 +258,21 @@ class StrategyDecision:
 
 @dataclass(frozen=True)
 class ParallelConfig:
-    """Distribution policy for a given mesh.
+    """Distribution policy for a given mesh: how parameters and attention
+    shard (``parallel.sharding``), whether the layers run as one scan,
+    what the backward pass recomputes, and the attention chunking.  The
+    mesh's batch axis is ``data`` and its tensor-parallel axis ``model``;
+    activations compute in bfloat16."""
 
-    ``grad_sync`` selects the data-parallel gradient synchronization
-    schedule — this is where the FRED technique surfaces in the runtime:
-
-      * ``flat``       — single ring all-reduce across all data-parallel
-                         replicas (the endpoint-based collective the paper's
-                         2D-mesh baseline is limited to).
-      * ``hierarchical`` — FRED-style reduction tree: reduce-scatter inside
-                         the pod (the L1 switch reduction), all-reduce across
-                         pods on the scattered shard (the L2 reduction), then
-                         all-gather inside the pod (the distribution tree).
-      * ``compressed`` — hierarchical + int8 quantization with error feedback
-                         on the cross-pod phase (software analogue of FRED's
-                         in-network traffic halving; beyond-paper).
-    """
-
-    mesh_axes: Tuple[str, ...] = ("data", "model")
-    dp_axes: Tuple[str, ...] = ("data",)          # batch-sharded axes
-    tp_axis: str = "model"
     param_sharding: str = "fsdp"                  # replicated | zero1 | fsdp
     attn_sharding: str = "heads"                  # heads | context
     scan_layers: bool = True
     remat: str = "block"                          # none | block | full
-    #   (under scan_layers the hybrid's shared block is always recomputed)
-    grad_sync: str = "hierarchical"               # flat | hierarchical | compressed
-    seq_shard: bool = True                        # SP: shard seq dim of activations
+    #   (under scan_layers the shared blocks are always recomputed)
     moe_ep_axis: str = ""                         # "" = TP-only MoE
-    compute_dtype: str = "bfloat16"
     param_dtype: str = "bfloat16"
-    optimizer_dtype: str = "float32"
     attn_q_chunk: int = 1024
     attn_k_chunk: int = 1024
-    use_pallas: bool = False                      # TPU-only fused kernels
     # sweep-driven auto-strategy (core/autostrategy.py): the simulator-
     # chosen StrategyDecision for this cell.  The default (all-zero)
     # decision means hand-set defaults / sweep not run.  Tuple-compatible

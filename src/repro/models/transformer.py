@@ -7,21 +7,18 @@ executed with ``lax.scan`` so the compiled HLO is O(1) in depth — essential
 for the 512-device dry-run compile times — with ``jax.checkpoint`` (remat)
 around the block body.
 
-Hybrid (zamba2) structure: ``num_layers`` Mamba2 blocks; after every
-``attn_every`` of them, a single *shared* attention block (one set of
-weights, applied num_layers/attn_every times, each application with its own
-KV cache slice — weights shared, activations not).  It runs as the ssm
-family does, one scan over the stacked Mamba2 layers, with the shared block
-inside the scan body under a ``lax.cond`` on the layer index.
-
-zamba2 (Zyphra's published block) runs in the same scan: before each
-layer of ``hybrid_layer_ids`` the scan body takes application j's shared
-block, ``j % n_shared_blocks``, by a ``lax.switch`` over the blocks sliced
-statically outside the scan; it reads concat(h, embedding), and its
-output T enters that Mamba2 layer's input only:
-h ← h + Mamba2(RMSNorm(h + L_j·T)).  The small per-application leaves
-(the MLP adapter, L_j) are indexed by j.  Each application keeps its own
-entry of the shared KV cache.
+The ssm, hybrid and zamba2 families run one scan over their stacked
+Mamba2 layers with one body.  A shared block (one set of weights, several
+applications, each with its own entry of the shared KV cache) runs at the
+layers of ``ModelConfig.application_layers``: the scan carries, per layer,
+which block runs (none, or block k) and which application it is, and the
+body takes it by a ``lax.switch``.  The hybrid's shared attention+MLP
+block runs after every ``attn_every``-th layer and updates the hidden
+state.  zamba2 (Zyphra's published block) takes block
+``j % n_shared_blocks`` before each layer of ``hybrid_layer_ids``; it
+reads concat(h, embedding), and its output T enters that Mamba2 layer's
+input only: h ← h + Mamba2(RMSNorm(h + L_j·T)), with the small
+per-application leaves (the MLP adapter, L_j) indexed by j.
 
 Each block kind runs under one ``jax.named_scope`` (``embed``, ``ssm`` with
 ``ssd`` inside it, ``attention``, ``mlp``, ``moe``, ``lm_head``, ``loss``;
@@ -147,13 +144,51 @@ def _embed_inputs(params, cfg, batch, constrain):
 
 def _maybe_remat(fn, pcfg: ParallelConfig):
     """Checkpoint one layer by ``pcfg.remat``.  Under the layer scan the
-    hybrid's shared block is recomputed in the backward pass whatever
-    ``remat`` says (``_scan_blocks``)."""
+    shared blocks are recomputed in the backward pass whatever ``remat``
+    says (``_scan_blocks``)."""
     if pcfg.remat == "none":
         return fn
     policy = (jax.checkpoint_policies.nothing_saveable if pcfg.remat == "full"
               else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     return jax.checkpoint(fn, policy=policy)
+
+
+def _shared_branches(params, cfg, pcfg, x, positions, constrain, **kw):
+    """The family's shared blocks as the layer scan's branches
+    ``(h, skv, j) -> (out, skv)`` for application j, branch 0 running
+    none, and where they run: "before" or "after" their layer.
+
+    zamba2: branch 1 + k is block k; it reads concat(h, embedding) and its
+    ``out`` is that layer's Mamba2 input h + L_j·T (before).  hybrid:
+    branch 1 is the shared attention+MLP block and its ``out`` the new
+    hidden state (after).  Other families have no branches."""
+    if cfg.family == "zamba2":
+        # each shared block sliced once, outside the scan: the switch picks
+        # among whole blocks, never a dynamic slice of them
+        blocks = [jax.tree.map(lambda a, k=k: a[k], params["shared_blocks"])
+                  for k in range(cfg.n_shared_blocks)]
+
+        def block(h, skv, j, k):
+            pick = lambda t: jax.lax.dynamic_index_in_dim(t, j, keepdims=False)
+            adapter = jax.tree.map(pick, params["adapter"])
+            t, skv = apply_zamba2_block(
+                blocks[k], adapter, cfg, pcfg, h, x, positions=positions,
+                cache=skv, constrain=constrain, cache_slot=j, **kw)
+            with jax.named_scope("shared_in"):
+                hin = h + t @ pick(params["shared_out"]["out_proj"])
+            return constrain(hin), skv
+        run = [functools.partial(block, k=k) for k in range(len(blocks))]
+        where = "before"
+    elif cfg.family == "hybrid":
+        def block(h, skv, j):
+            h, skv, _, _ = apply_attn_block(
+                params["shared_attn"], cfg, pcfg, h, positions=positions,
+                cache=skv, constrain=constrain, cache_slot=j, **kw)
+            return h, skv
+        run, where = [block], "after"
+    else:
+        return [], None
+    return [lambda h, skv, j: (h, skv)] + run, where
 
 
 def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
@@ -185,14 +220,16 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
         return carry, stacked
 
     if is_ssm_family:
-        # one scan over the stacked Mamba2 layers; the hybrid's shared block
-        # runs inside it after every ``attn_every``-th layer, so the stacked
-        # weights and state are scan operands whole, never sliced by group
-        every = cfg.attn_every if cfg.family == "hybrid" else 0
+        # one scan over the stacked Mamba2 layers with the shared blocks
+        # inside it, so the stacked weights and state are scan operands
+        # whole, never sliced by group
+        branches, where = _shared_branches(
+            params, cfg, pcfg, x, positions, constrain, mode=mode,
+            cache_index=cache_index, cache_len=cache_len)
         skv = None
-        if cfg.family != "ssm" and mode == "decode":
+        if branches and mode == "decode":
             skv = shared_kv
-        elif cfg.family != "ssm" and mode == "prefill":
+        elif branches and mode == "prefill":
             # each application writes its prompt's KV into a zero buffer
             skv = _shared_kv_zeros(cfg, x.shape[0], cache_len or x.shape[1],
                                    x.dtype)
@@ -200,109 +237,52 @@ def _scan_blocks(params, cfg, pcfg, x, positions, constrain, *,
 
         def mamba_layer(h, u, bp, st):
             """h + Mamba2(RMSNorm(u)), and the layer's new state (decode
-            and prefill).  The norm reads h itself, or zamba2's h + L_j·T."""
-            u = rms_norm(u, bp["ln"], cfg.norm_eps)
+            and prefill).  ``u`` is None for h itself, or zamba2's
+            h + L_j·T."""
+            u = rms_norm(h if u is None else u, bp["ln"], cfg.norm_eps)
             if mode == "train":
                 return constrain(h + mamba2_forward(bp["ssm"], u, cfg)), None
             out, new_st = mamba2_forward(bp["ssm"], u, cfg, state=st,
                                          return_state=True)
             return constrain(h + out), new_st
+        layer = _maybe_remat(mamba_layer, pcfg)
 
-        if cfg.family == "zamba2":
-            x0 = x
-            nb = cfg.n_shared_blocks
-            # each shared block sliced once, outside the scan: the switch
-            # below picks among whole blocks, never a dynamic slice of them
-            shared = [jax.tree.map(lambda a, k=k: a[k],
-                                   params["shared_blocks"])
-                      for k in range(nb)]
-
-            def hybrid_in(h, skv, j, k):
-                """Application j, of block k: the Mamba2 layer's input
-                h + L_j·T, and the cache with its KV written."""
-                pick = lambda t: jax.lax.dynamic_index_in_dim(
-                    t, j, keepdims=False)
-                adapter = jax.tree.map(pick, params["adapter"])
-                t, skv = apply_zamba2_block(
-                    shared[k], adapter, cfg, pcfg, h, x0,
-                    positions=positions, mode=mode, cache=skv,
-                    cache_index=cache_index, cache_len=cache_len,
-                    constrain=constrain, cache_slot=j)
-                with jax.named_scope("shared_in"):
-                    hin = h + t @ pick(params["shared_out"]["out_proj"])
-                return constrain(hin), skv
-
-            branches = [lambda h, skv, j: (h, skv)] + [
-                functools.partial(hybrid_in, k=k) for k in range(nb)]
-
-            def mamba_input(h, skv, sel, j):
-                """Branch ``sel``: 0 a plain layer, 1 + k block k."""
-                if isinstance(sel, (int, np.integer)):   # unrolled
-                    return branches[sel](h, skv, j)
-                return jax.lax.switch(sel, branches, h, skv, j)
-            if pcfg.scan_layers:
-                # as the hybrid's shared block: keep only the inputs
-                mamba_input = jax.checkpoint(
-                    mamba_input,
-                    policy=jax.checkpoint_policies.nothing_saveable)
-
-            def zbody(carry, xs):
-                h, skv = carry
-                bp, st, (sel, j) = xs
-                bp = layer_constrain(bp)
-                hin, skv = mamba_input(h, skv, sel, j)
-                h, new_st = _maybe_remat(mamba_layer, pcfg)(h, hin, bp, st)
-                return (h, skv), new_st
-
-            app = np.full(L, -1, np.int32)
-            app[list(cfg.hybrid_layer_ids)] = np.arange(cfg.n_applications)
-            sel = np.where(app >= 0, 1 + app % nb, 0).astype(np.int32)
-            (x, skv), new_ssm = maybe_scan(
-                zbody, (x, skv),
-                (params["blocks"], scan_ssm, (sel, np.maximum(app, 0))), L)
-            return x, None, new_ssm, skv, None, jnp.zeros((), jnp.float32)
-
-        def shared_block(h, skv, g):
-            """Application g of the shared attention+MLP block; its KV is
-            entry g of the carried (groups, ...) cache."""
-            h, skv, _, _ = apply_attn_block(
-                params["shared_attn"], cfg, pcfg, h, positions=positions,
-                mode=mode, cache=skv, cache_index=cache_index,
-                cache_len=cache_len, constrain=constrain, cache_slot=g)
-            return h, skv
-
-        def shared_after(h, skv, i):
-            """The shared block after layer i, where layer i ends a group."""
-            if isinstance(i, (int, np.integer)):   # unrolled: a static if
-                if i % every == every - 1:
-                    h, skv = shared_block(h, skv, i // every)
-                return h, skv
-            return jax.lax.cond(i % every == every - 1, shared_block,
-                                lambda h, skv, g: (h, skv), h, skv, i // every)
-        if every and pcfg.scan_layers:
+        def shared(h, skv, sel, j):
+            """Branch ``sel`` at application j: 0 none, 1 + k block k."""
+            if isinstance(sel, (int, np.integer)):   # unrolled: a static call
+                return branches[sel](h, skv, j)
+            return jax.lax.switch(sel, branches, h, skv, j)
+        if branches and pcfg.scan_layers:
             # autodiff keeps one residual slot a layer under the scan: what
-            # the shared block saved would be stacked num_layers times, zero
-            # in all but one layer of attn_every.  Keep only its input and
-            # recompute it in the backward pass.
-            shared_after = jax.checkpoint(
-                shared_after, policy=jax.checkpoint_policies.nothing_saveable)
+            # a shared block saved would be stacked num_layers times, zero
+            # in every layer it skips.  Keep only its inputs and recompute
+            # it in the backward pass.
+            shared = jax.checkpoint(
+                shared, policy=jax.checkpoint_policies.nothing_saveable)
 
         def body(carry, xs):
             h, skv = carry
-            bp, st, i = xs
+            bp, st, sched = xs
             # re-pin the per-layer slice to its stored sharding so FSDP
             # all-gathers happen inside the loop body, not on the whole stack
             bp = layer_constrain(bp)
-            run = _maybe_remat(lambda h, bp, st: mamba_layer(h, h, bp, st),
-                               pcfg)
-            h, new_st = run(h, bp, st)
-            if every:
-                h, skv = shared_after(h, skv, i)
+            u = None
+            if where == "before":
+                u, skv = shared(h, skv, *sched)
+            h, new_st = layer(h, u, bp, st)
+            if where == "after":
+                h, skv = shared(h, skv, *sched)
             return (h, skv), new_st
 
-        index = np.arange(L, dtype=np.int32) if every else None
+        sched = None
+        if branches:
+            # per layer: the branch that runs and the application it is
+            app = np.full(L, -1, np.int32)
+            app[list(cfg.application_layers)] = np.arange(cfg.n_applications)
+            sel = np.where(app >= 0, 1 + app % (len(branches) - 1), 0)
+            sched = (sel.astype(np.int32), np.maximum(app, 0))
         (x, skv), new_ssm = maybe_scan(body, (x, skv),
-                                       (params["blocks"], scan_ssm, index), L)
+                                       (params["blocks"], scan_ssm, sched), L)
         # train carries no state: new_ssm and skv are None there
         return x, None, new_ssm, skv, None, jnp.zeros((), jnp.float32)
 
@@ -380,11 +360,10 @@ def loss_fn(params, batch, cfg: ModelConfig, pcfg: ParallelConfig,
 
 def _shared_kv_zeros(cfg: ModelConfig, batch: int, cache_len: int,
                      dtype) -> KVCache:
-    """The hybrid's shared-block KV cache, one entry per application."""
+    """The shared blocks' KV cache, one entry per application."""
     eff_len = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
-    apps = (cfg.n_applications if cfg.family == "zamba2"
-            else cfg.num_layers // cfg.attn_every)
-    z = jnp.zeros((apps, batch, eff_len, cfg.n_kv_heads, cfg.head_dim), dtype)
+    z = jnp.zeros((cfg.n_applications, batch, eff_len, cfg.n_kv_heads,
+                   cfg.head_dim), dtype)
     return KVCache(z, z)
 
 

@@ -16,10 +16,9 @@ Two modes:
   cross-wafer DP, the inter-wafer topology (ring / fully_connected /
   switch, ``core.cluster``) — for the cell under the frozen defaults'
   OptimConfig/remat settings, and the decision lands in
-  ``ParallelConfig.auto_strategy`` (plus ``grad_sync="hierarchical"``
-  for cross-wafer DP).  The JAX mesh itself is built by the launcher —
-  the recorded strategy is what the dry-run logs and what wafer-side
-  placement (``core.placement``) executes.
+  ``ParallelConfig.auto_strategy``.  The JAX mesh itself is built by the
+  launcher — the recorded strategy is what the dry-run logs and what
+  wafer-side placement (``core.placement``) executes.
 
 §Perf hillclimbs still override via ``pcfg_overrides`` after either mode.
 """
@@ -92,10 +91,4 @@ def cell_policy(cfg: ModelConfig, shape: ShapeConfig, mesh,
         ep=st.ep, sp=st.sp,
         inter_topology=decision.inter_topology,
         defect_seed=getattr(decision, "defect_seed", None)))
-    if st.wafers > 1:
-        # cross-wafer DP must use the hierarchical reduction: RS within
-        # the wafer, the chosen inter-wafer collective (ring ring-AR /
-        # fully-connected direct exchange / in-switch reduction) on the
-        # shard, AG within
-        pcfg = pcfg.replace(grad_sync="hierarchical")
     return pcfg, ocfg

@@ -41,6 +41,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.config import ModelConfig, ParallelConfig
 from repro.models.modules import AxisNames
 
+# the mesh's tensor-parallel axis and its batch-sharded axes ("pod" joins
+# them in front where the mesh has it)
+TP_AXIS = "model"
+DP_AXES = ("data",)
+
 
 def _axis_size(mesh: Mesh, name) -> int:
     if name is None:
@@ -58,16 +63,10 @@ class Ruleset:
 
     def __post_init__(self):
         mesh, cfg, pcfg = self.mesh, self.cfg, self.pcfg
-        tp = pcfg.tp_axis if pcfg.tp_axis in mesh.shape else None
-        dp: Tuple[str, ...] = tuple(a for a in pcfg.dp_axes if a in mesh.shape)
-        if "pod" in mesh.shape and "pod" not in dp:
+        tp = TP_AXIS if TP_AXIS in mesh.shape else None
+        dp: Tuple[str, ...] = tuple(a for a in DP_AXES if a in mesh.shape)
+        if "pod" in mesh.shape:
             dp = ("pod",) + dp
-        if tp is None and "model" in mesh.shape and \
-                "model" not in dp and pcfg.tp_axis == "":
-            # no-TP mapping: the model axis becomes extra data parallelism
-            # (a *parallelization strategy* choice, not a mesh change — the
-            # flexibility the paper argues the fabric must support)
-            dp = dp + ("model",)
         tp_size = _axis_size(mesh, tp)
         self.dp = dp
         self.tp = tp
@@ -177,7 +176,7 @@ class Ruleset:
 
     def act_spec(self, kind: str, global_batch: int, *, ndim: int = 3) -> P:
         b = self.batch_axes(global_batch)
-        seq = self.tp if (self.pcfg.seq_shard and kind == "residual") else None
+        seq = self.tp if kind == "residual" else None
         if kind == "residual":
             return P(b, seq, None)
         if kind == "logits":

@@ -31,6 +31,10 @@ class TrainState(NamedTuple):
     opt: AdamState
 
 
+# activations, frontend embeddings and the decode state
+COMPUTE_DTYPE = jnp.bfloat16
+
+
 def _dtype(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
             "float16": jnp.float16}[name]
@@ -47,7 +51,6 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig, pcfg: ParallelConfig
     Modality frontends are stubs per the task spec: ``patch_embeds`` /
     ``frames`` are precomputed embeddings."""
     B, S = shape.global_batch, shape.seq_len
-    cdt = _dtype(pcfg.compute_dtype)
     i32 = jnp.int32
     sd = jax.ShapeDtypeStruct
 
@@ -58,10 +61,11 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig, pcfg: ParallelConfig
     batch = {}
     s_text = S
     if cfg.family == "vlm":
-        batch["patch_embeds"] = sd((B, cfg.n_patches, cfg.d_model), cdt)
+        batch["patch_embeds"] = sd((B, cfg.n_patches, cfg.d_model),
+                                   COMPUTE_DTYPE)
         s_text = S - cfg.n_patches
     if cfg.family == "audio":
-        batch["frames"] = sd((B, cfg.enc_seq, cfg.d_model), cdt)
+        batch["frames"] = sd((B, cfg.enc_seq, cfg.d_model), COMPUTE_DTYPE)
     batch["tokens"] = sd((B, s_text), i32)
     if shape.kind == "train":
         batch["labels"] = sd((B, s_text), i32)
@@ -240,11 +244,10 @@ def make_decode_setup(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     ruleset, param_shapes, axes, param_shardings = _param_setup(cfg, pcfg, mesh)
     constrain = ruleset.constrain_fn(shape.global_batch)
     lc = make_layer_constrain(ruleset, axes["blocks"])
-    cdt = _dtype(pcfg.compute_dtype)
 
     state_shapes = jax.eval_shape(
         lambda: tfm.init_decode_state(cfg, shape.global_batch,
-                                      shape.seq_len, cdt))
+                                      shape.seq_len, COMPUTE_DTYPE))
     state_shardings = ruleset.decode_state_shardings(cfg, shape.global_batch)
 
     def decode(params, state, tokens):

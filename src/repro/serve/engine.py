@@ -41,12 +41,6 @@ class Request:
 class EngineConfig:
     max_batch: int = 8
     cache_len: int = 512
-    # Serving SLO / traffic parameters (ISSUE 10): consumed by the
-    # analytical serving cost model (core/serving via a serving
-    # Objective) and recorded by `launch.dryrun --serving` next to the
-    # measured per-token decode latency.  None = no SLO attached.
-    target_p99_ms: Optional[float] = None
-    arrival_rate_rps: Optional[float] = None
 
 
 def choose_tokens(logits, temperature, top_k, uid, key, step, *,

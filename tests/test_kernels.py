@@ -79,15 +79,3 @@ def test_quant8_matches_jnp(n, block):
     np.testing.assert_allclose(np.asarray(x1), np.asarray(x2), rtol=1e-6)
     # quantization error bounded by half a quantum per block
     assert float(jnp.max(jnp.abs(x1 - x))) <= float(jnp.max(s1)) * 0.51
-
-
-def test_ops_dispatch():
-    from repro.kernels import ops
-    B, S, H, hd = 1, 64, 2, 32
-    q = jax.random.normal(KEY, (B, S, H, hd)) * 0.5
-    k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, S, 1, hd)) * 0.5
-    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, S, 1, hd))
-    a1 = ops.attention(q, k, v, use_pallas=False)
-    a2 = ops.attention(q, k, v, use_pallas=True)
-    np.testing.assert_allclose(np.asarray(a1), np.asarray(a2),
-                               atol=2e-5, rtol=1e-4)

@@ -452,6 +452,32 @@ def test_hybrid_scan_matches_layer_loop(case):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), **close)
 
 
+# the published schedules: at which layers a shared block runs
+SCHEDULES = {
+    ("zamba2-2.7b", "full"): (5, 11, 17, 23, 29, 35, 41, 47, 53),
+    ("zamba2-2.7b", "reduced"): (1, 3),
+    ("zamba2-7b", "full"): (6, 11, 17, 23, 29, 35, 41, 47, 53, 59, 65, 71,
+                            77),
+    ("zamba2-7b", "reduced"): (1, 3, 5),
+}
+
+
+@pytest.mark.parametrize("arch,size", sorted(SCHEDULES))
+def test_shared_block_schedule(arch, size):
+    """The layers at which a shared block runs, as the published configs
+    give them (zamba2-2.7b: after every sixth of 54; zamba2-7b: before
+    each of its 13 ``hybrid_layer_ids``), how many applications that is,
+    and the shared KV cache's one entry per application."""
+    cfg = get_config(arch)
+    if size == "reduced":
+        cfg = cfg.reduced()
+    layers = SCHEDULES[arch, size]
+    assert cfg.application_layers == layers
+    assert cfg.n_applications == len(layers)
+    kv = jax.eval_shape(lambda: tfm._shared_kv_zeros(cfg, 2, 8, jnp.bfloat16))
+    assert kv.k.shape[0] == kv.v.shape[0] == len(layers)
+
+
 # --------------------------------------------------------------------------
 # zamba2: Zyphra's published block against the benchmark's plain reference
 # --------------------------------------------------------------------------
